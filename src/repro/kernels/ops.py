@@ -1,8 +1,11 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On a real TPU these dispatch compiled Pallas; everywhere else (this CPU
-container) they run in interpret mode, which executes the kernel bodies in
-Python and validates them against the same BlockSpec tiling the TPU would use.
+On a TPU backend these dispatch compiled Pallas (Mosaic); on any other
+backend, which is the CPU test suite, they run in interpret mode.  Interpret
+mode checks the kernel bodies and index maps but not what the chip refuses
+(tiling, scoped VMEM): `tests/test_tpu_compile.py` compiles for a described
+v5e, and `chip_smoke.py` calls the kernels with `interpret=False` and fails
+without a TPU rather than falling back.
 """
 from __future__ import annotations
 

@@ -38,6 +38,7 @@ KV blocks.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -52,16 +53,56 @@ _NEG = float(-(1 << 24))
 
 
 def _lut_gather(d: jax.Array, table_f: jax.Array) -> jax.Array:
-    """(r, c) int32 in [0,255] -> table values, as one-hot MXU matmul."""
+    """(r, c) int32 in [0,255] -> table values, as one-hot MXU matmul.
+
+    HIGHEST precision keeps the read exact on the chip: the Q1.15 entries
+    need 16 significant bits, and a single bf16 MXU pass keeps 8."""
     onehot = (d[..., None] == jnp.arange(256, dtype=jnp.int32)).astype(jnp.float32)
     return jax.lax.dot_general(
         onehot.reshape(-1, 256), table_f.reshape(256, 1),
         (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     ).reshape(d.shape)
 
 
-def _kv4_dequant(packed: jax.Array, levels_f: jax.Array) -> jax.Array:
-    """(r, Dh/2) int8 packed 4-bit KV codes -> (r, Dh) f32 codebook values.
+# score rows per LUT exp gather step in `_lut_exp`: one sublane tile, so
+# the loop's row offset is tile-aligned at every block width
+_LUT_ROWS = 8
+
+
+def _lut_exp(codes: jax.Array, m: jax.Array, buf_ref,
+             table_f: jax.Array) -> jax.Array:
+    """Masked LUT exp of (r, c) score codes against the (r, 1) row max m.
+
+    Masked scores hold exactly `_NEG` and get e = 0.  The table indices
+    (-1 where masked) go through the (r, c) f32 scratch `buf_ref` in place,
+    `_LUT_ROWS` rows per loop step, which bounds the live one-hot to
+    (_LUT_ROWS * c, 256) (a whole 32 x 256 block overruns the 16 MB scoped
+    VMEM of a v5e); the per-element arithmetic is that of one whole-block
+    gather."""
+    buf_ref[...] = jnp.where(codes > _NEG / 2, jnp.clip(m - codes, 0, 255),
+                             -1.0)
+
+    def step(i, carry):
+        rows = pl.ds(pl.multiple_of(i * _LUT_ROWS, _LUT_ROWS), _LUT_ROWS)
+        d = buf_ref[rows, :]
+        buf_ref[rows, :] = jnp.where(
+            d >= 0, _lut_gather(d.astype(jnp.int32), table_f), 0.0)
+        return carry
+
+    jax.lax.fori_loop(0, codes.shape[0] // _LUT_ROWS, step, 0)
+    return buf_ref[...]
+
+
+# KV rows dequantized per one-hot matmul in `_kv4_dequant`: the (rows * Dh,
+# 16) f32 one-hot is lane-padded to 128 in VMEM (2 MB at 32 rows of Dh 128),
+# so a whole 256-row block at once overruns the 16 MB scoped VMEM of a v5e
+_KV4_ROWS = 32
+
+
+def _kv4_dequant(src_ref, dst_ref, levels_f: jax.Array) -> jax.Array:
+    """(..., r, Dh/2) int8 packed 4-bit KV block -> (r, Dh) f32 codebook
+    values, written to the `dst_ref` scratch and returned.
 
     Nibble unpack (low half of the head dim in the low nibbles, high half in
     the high — `quant.pack_codes4`) followed by a 16-entry one-hot x table
@@ -69,15 +110,27 @@ def _kv4_dequant(packed: jax.Array, levels_f: jax.Array) -> jax.Array:
     KV block load so no f32 (or even int8) KV plane is ever materialized in
     HBM.  The levels are int8-exact integers, so the f32 Score dot against
     an int8 q reproduces the behavioral int32 einsum exactly (|sum| <=
-    256*128*127 < 2^24)."""
-    p = packed.astype(jnp.int32) & 0xFF
-    codes = jnp.concatenate([p & 0xF, (p >> 4) & 0xF], axis=-1)
-    onehot = (codes[..., None] == jnp.arange(16, dtype=jnp.int32)
-              ).astype(jnp.float32)
-    return jax.lax.dot_general(
-        onehot.reshape(-1, 16), levels_f.reshape(16, 1),
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-    ).reshape(codes.shape)
+    256*128*127 < 2^24).  A loop over row chunks bounds the live one-hot to
+    one chunk; the per-element arithmetic is that of one whole-block read."""
+    rows = dst_ref.shape[0]
+    n = math.gcd(rows, _KV4_ROWS)
+    lead = (0,) * (src_ref.ndim - 2)
+
+    def chunk(i, carry):
+        lo = pl.multiple_of(i * n, n)
+        p = src_ref[lead + (pl.ds(lo, n), slice(None))].astype(jnp.int32) & 0xFF
+        codes = jnp.concatenate([p & 0xF, (p >> 4) & 0xF], axis=-1)
+        onehot = (codes[..., None] == jnp.arange(16, dtype=jnp.int32)
+                  ).astype(jnp.float32)
+        dst_ref[pl.ds(lo, n), :] = jax.lax.dot_general(
+            onehot.reshape(-1, 16), levels_f.reshape(16, 1),
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
+        ).reshape(codes.shape)
+        return carry
+
+    jax.lax.fori_loop(0, rows // n, chunk, 0)
+    return dst_ref[...]
 
 
 def _block_needed(k_start, block_k, q_lo, q_hi, kv_len, causal: bool,
@@ -97,10 +150,10 @@ def _attn_kernel(
     pt_ref,                            # SMEM (nb, n_k_blocks) page table
     q_ref, qs_ref, k_ref, ks_ref, v_ref, vs_ref, table_ref, lv_ref,
     out_ref, iters_ref,
-    m_ref, denom_ref, acc_ref,
-    *, block_q: int, block_k: int, n_k_blocks: int, causal: bool,
+    m_ref, denom_ref, acc_ref, lut_ref, *deq_ref,
+    block_q: int, block_k: int, n_k_blocks: int, causal: bool,
     window: int, sm_scale: float, score_scale: float, input_bits: int,
-    table_frac_bits: int, gather_chunk: int, prune: bool, h_per_b: int,
+    table_frac_bits: int, prune: bool, h_per_b: int,
     kv_bits: int,
 ):
     ki = pl.program_id(2)
@@ -141,23 +194,24 @@ def _attn_kernel(
 
     @pl.when(needed)
     def _body():
-        iters_ref[0, 0] += 1
+        iters_ref[...] += 1
         q = q_ref[...][0]                  # (bq, Dh) int8
-        k = k_ref[...].reshape(block_k, k_ref.shape[-1])   # (bk, Dh[/2]) int8
         if kv_bits == 4:
             # LUT-fused dequant at the block load: exact int8-valued f32
             # levels, so this f32 dot == the behavioral int32 einsum
-            k = _kv4_dequant(k, lv_ref[...].astype(jnp.float32))
+            k = _kv4_dequant(k_ref, deq_ref[0],
+                             lv_ref[...].astype(jnp.float32))  # (bk, Dh) f32
             s_int = jax.lax.dot_general(   # (bq, bk) exact-integer f32
                 q.astype(jnp.float32), k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
         else:
+            k = k_ref[...].reshape(block_k, k_ref.shape[-1])  # (bk, Dh) int8
             s_int = jax.lax.dot_general(   # (bq, bk) int32 — the PIM Score engine
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.int32)
-        qs = qs_ref[...][0]                # (bq,) f32
-        ks = ks_ref[...].reshape(block_k)  # (bk,) f32
-        s_real = s_int.astype(jnp.float32) * qs[:, None] * ks[None, :] * sm_scale
+        qs = qs_ref[...].reshape(block_q, 1)        # (bq, 1) f32
+        ks = ks_ref[...].reshape(1, block_k)        # (1, bk) f32
+        s_real = s_int.astype(jnp.float32) * qs * ks * sm_scale
 
         # requantize to the 8-bit score port
         qmax = float((1 << (input_bits - 1)) - 1)
@@ -186,24 +240,20 @@ def _attn_kernel(
         resc = _lut_gather(d_resc, table_f) / float(1 << table_frac_bits)
         resc = jnp.where(m_old <= _NEG / 2, jnp.zeros_like(resc), resc)
 
-        e = jnp.zeros((block_q, block_k), jnp.float32)
-        for ci in range(block_k // gather_chunk):
-            lo = ci * gather_chunk
-            c_c = jax.lax.dynamic_slice(codes, (0, lo), (block_q, gather_chunk))
-            m_c = jax.lax.dynamic_slice(mask, (0, lo), (block_q, gather_chunk))
-            d = jnp.clip(m_new - c_c, 0, 255).astype(jnp.int32)
-            e_c = jnp.where(m_c, _lut_gather(d, table_f), 0.0)
-            e = jax.lax.dynamic_update_slice(e, e_c, (0, lo))
+        e = _lut_exp(codes, m_new, lut_ref, table_f)
 
         denom_ref[...] = denom_ref[...] * resc + jnp.sum(e, axis=-1, keepdims=True)
-        v = v_ref[...].reshape(block_k, v_ref.shape[-1])   # (bk, Dh[/2]) int8
-        vs = vs_ref[...].reshape(block_k)  # (bk,) f32
+        vs = vs_ref[...].reshape(block_k, 1)        # (bk, 1) f32
         if kv_bits == 4:
-            v_deq = _kv4_dequant(v, lv_ref[...].astype(jnp.float32)) * vs[:, None]
+            v_deq = _kv4_dequant(v_ref, deq_ref[0],
+                                 lv_ref[...].astype(jnp.float32)) * vs
         else:
-            v_deq = v.astype(jnp.float32) * vs[:, None]
+            v = v_ref[...].reshape(block_k, v_ref.shape[-1])  # (bk, Dh) int8
+            v_deq = v.astype(jnp.float32) * vs
         pv = jax.lax.dot_general(
-            e, v_deq, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            e, v_deq, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
         )
         acc_ref[...] = acc_ref[...] * resc + pv
         m_ref[...] = m_new
@@ -217,13 +267,13 @@ def _attn_kernel(
     jax.jit,
     static_argnames=(
         "pim_cfg", "lut_cfg", "causal", "window",
-        "block_q", "block_k", "gather_chunk", "interpret",
+        "block_q", "block_k", "interpret",
         "prune", "return_iters",
     ),
 )
 def pim_attention_pallas(
     q_q: jax.Array,        # (BH, Sq, Dh) int8
-    q_scale: jax.Array,    # (BH, Sq) f32
+    q_scale: jax.Array,    # (BH, Sq) float, cast to f32
     k_q: jax.Array,        # (BHkv, Sk, Dh) int8, or (Hkv, P, ps, Dh) paged;
                            #   last dim Dh/2 when packed 4-bit (kv_bits=4)
     k_scale: jax.Array,    # (BHkv, Sk) f32, or (Hkv, P, ps) paged
@@ -237,7 +287,6 @@ def pim_attention_pallas(
     window: int = 0,
     block_q: int = 32,
     block_k: int = 256,
-    gather_chunk: int = 128,
     interpret: bool = False,
     prune: bool = True,
     return_iters: bool = False,
@@ -326,7 +375,6 @@ def pim_attention_pallas(
         causal=causal, window=window,
         sm_scale=1.0 / (Dh ** 0.5), score_scale=lut_cfg.score_scale,
         input_bits=lut_cfg.input_bits, table_frac_bits=frac,
-        gather_chunk=min(gather_chunk, block_k),
         prune=prune, h_per_b=h_per_b, kv_bits=kv_bits,
     )
     levels = jnp.asarray(KV4_LEVELS, jnp.float32)            # (16,) codebook
@@ -334,30 +382,36 @@ def pim_attention_pallas(
         [jnp.broadcast_to(q_off, (nb,)), jnp.broadcast_to(kvl, (nb,)),
          jnp.broadcast_to(ql, (nb,))]
     )                                                        # (3, nb)
+    # Mosaic tiles the last two dims of every block by (8, 128) unless a dim
+    # spans its whole array, so each f32 scale block is a (1, n) lane row:
+    # a singleton axis before the K/V token axis, and q scales split per q
+    # block.  Rows keep the planes compact in HBM (a trailing singleton
+    # axis would pad every entry to 128 lanes); the kernel reshapes the q
+    # and V rows into the columns that scale its rows.  The q scales come
+    # in the model's dtype (bf16 when serving), and Mosaic reshapes a bf16
+    # row into a column only after the exact cast to f32.
+    q_scale = q_scale.astype(jnp.float32).reshape(
+        BH, Sqp // block_q, 1, block_q)
+    k_scale = k_scale[..., None, :]
+    v_scale = v_scale[..., None, :]
     if page_table is not None:
         # flat q row b*H + h attends kv head (b*H + h) // q_per_kv; its page
         # pool row is that modulo Hkv, and the page comes from the slot's
         # scalar-prefetched table (clamped to the trash page when -1 — the
         # guarded body never reads the placeholder)
-        kv_spec = pl.BlockSpec(
-            (1, 1, block_k, Dhk),
-            lambda b, i, k, s, t, qpk=q_per_kv, hk=Hkv, hb=h_per_b: (
-                jax.lax.rem(b // qpk, hk),
-                jnp.maximum(t[b // hb, k], 0), 0, 0),
-        )
-        kvs_spec = pl.BlockSpec(
-            (1, 1, block_k),
-            lambda b, i, k, s, t, qpk=q_per_kv, hk=Hkv, hb=h_per_b: (
-                jax.lax.rem(b // qpk, hk),
-                jnp.maximum(t[b // hb, k], 0), 0),
-        )
+        def page_index(b, i, k, s, t, qpk=q_per_kv, hk=Hkv, hb=h_per_b):
+            return (jax.lax.rem(b // qpk, hk),
+                    jnp.maximum(t[b // hb, k], 0), 0, 0)
+        kv_spec = pl.BlockSpec((1, 1, block_k, Dhk), page_index)
+        scale_spec = pl.BlockSpec((1, 1, 1, block_k), page_index)
     else:
         kv_spec = pl.BlockSpec(
             (1, block_k, Dhk),
             lambda b, i, k, s, t, qpk=q_per_kv: (b // qpk, k, 0),
         )
-        kvs_spec = pl.BlockSpec(
-            (1, block_k), lambda b, i, k, s, t, qpk=q_per_kv: (b // qpk, k)
+        scale_spec = pl.BlockSpec(
+            (1, 1, block_k),
+            lambda b, i, k, s, t, qpk=q_per_kv: (b // qpk, 0, k),
         )
     out, iters = pl.pallas_call(
         kernel,
@@ -366,30 +420,37 @@ def pim_attention_pallas(
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, block_q, Dh), lambda b, i, k, s, t: (b, i, 0)),
-                pl.BlockSpec((1, block_q), lambda b, i, k, s, t: (b, i)),
+                pl.BlockSpec((1, 1, 1, block_q),
+                             lambda b, i, k, s, t: (b, i, 0, 0)),
                 kv_spec,
-                kvs_spec,
+                scale_spec,
                 kv_spec,
-                kvs_spec,
+                scale_spec,
                 pl.BlockSpec((256,), lambda b, i, k, s, t: (0,)),
                 pl.BlockSpec((16,), lambda b, i, k, s, t: (0,)),
             ],
             out_specs=[
                 pl.BlockSpec((1, block_q, Dh), lambda b, i, k, s, t: (b, i, 0)),
-                pl.BlockSpec((1, 1), lambda b, i, k, s, t: (b, i)),
+                pl.BlockSpec((1, 1, 1, 1),
+                             lambda b, i, k, s, t: (b, i, 0, 0)),
             ],
             scratch_shapes=[
                 pltpu.VMEM((block_q, 1), jnp.float32),
                 pltpu.VMEM((block_q, 1), jnp.float32),
                 pltpu.VMEM((block_q, Dh), jnp.float32),
+                pltpu.VMEM((block_q, block_k), jnp.float32),   # LUT exp
+                # 4-bit only: the dequantized K, then V, block
+                *([pltpu.VMEM((block_k, Dh), jnp.float32)]
+                  if kv_bits == 4 else []),
             ],
         ),
         out_shape=[
             jax.ShapeDtypeStruct((BH, Sqp, Dh), jnp.float32),
-            jax.ShapeDtypeStruct((BH, Sqp // block_q), jnp.int32),
+            jax.ShapeDtypeStruct((BH, Sqp // block_q, 1, 1), jnp.int32),
         ],
         interpret=interpret,
     )(scalars, pt, q_q, q_scale, k_q, k_scale, v_q, v_scale, table, levels)
+    iters = iters.reshape(BH, Sqp // block_q)
     out = out[:, :Sq]
     if return_iters:
         return out, iters

@@ -53,15 +53,15 @@ from repro.configs.base import LUTSoftmaxConfig, PIMConfig
 from repro.core.lut_softmax import build_exp_table
 from repro.core.quant import KV4_LEVELS
 from repro.kernels.pim_attention import (_NEG, _block_needed, _kv4_dequant,
-                                         _lut_gather)
+                                         _lut_exp)
 
 
 def _decode_kernel(
     scalars_ref,                  # SMEM (3, nb): [q_pos_b, kv_len_b, q_len_b]
     pt_ref,                            # SMEM (nb, n_k_blocks) page table
     q_ref, qs_ref, k_ref, ks_ref, v_ref, vs_ref, table_ref, lv_ref,
-    m_ref, den_ref, acc_ref, iters_ref,
-    *, block_k: int, r_pad: int, g: int, sq: int, causal: bool, window: int,
+    m_ref, den_ref, acc_ref, iters_ref, lut_ref, *deq_ref,
+    block_k: int, r_pad: int, g: int, sq: int, causal: bool, window: int,
     sm_scale: float, score_scale: float, input_bits: int, hkv_per_b: int,
     kv_bits: int,
 ):
@@ -87,23 +87,24 @@ def _decode_kernel(
 
     @pl.when(needed)
     def _body():
-        iters_ref[0, 0] = 1
+        iters_ref[...] = jnp.ones_like(iters_ref)
         q = q_ref[...].reshape(r_pad, q_ref.shape[-1])    # (R, Dh) int8
-        k = k_ref[...].reshape(block_k, k_ref.shape[-1])  # (bk, Dh[/2]) int8
         if kv_bits == 4:
             # LUT-fused codebook dequant at the page load: exact int8-valued
             # f32 levels, so this f32 dot == the behavioral int32 einsum
-            k = _kv4_dequant(k, lv_ref[...].astype(jnp.float32))
+            k = _kv4_dequant(k_ref, deq_ref[0],
+                             lv_ref[...].astype(jnp.float32))  # (bk, Dh) f32
             s_int = jax.lax.dot_general(   # (R, bk) exact-integer f32
                 q.astype(jnp.float32), k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
         else:
+            k = k_ref[...].reshape(block_k, k_ref.shape[-1])  # (bk, Dh) int8
             s_int = jax.lax.dot_general(   # (R, bk) int32 — the PIM Score engine
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.int32)
-        qs = qs_ref[...].reshape(r_pad)                   # (R,) f32
-        ks = ks_ref[...].reshape(block_k)                 # (bk,) f32
-        s_real = s_int.astype(jnp.float32) * qs[:, None] * ks[None, :] * sm_scale
+        qs = qs_ref[...].reshape(r_pad, 1)                # (R, 1) f32
+        ks = ks_ref[...].reshape(1, block_k)              # (1, bk) f32
+        s_real = s_int.astype(jnp.float32) * qs * ks * sm_scale
 
         qmax = float((1 << (input_bits - 1)) - 1)
         codes = jnp.clip(jnp.round(s_real / score_scale), -qmax - 1.0, qmax)
@@ -126,25 +127,26 @@ def _decode_kernel(
 
         table_f = table_ref[...].astype(jnp.float32)
         m = jnp.max(codes, axis=-1, keepdims=True)           # (R, 1)
-        d = jnp.clip(m - codes, 0, 255).astype(jnp.int32)
-        e = jnp.where(mask, _lut_gather(d, table_f), 0.0)    # (R, bk)
-        v = v_ref[...].reshape(block_k, v_ref.shape[-1])     # (bk, Dh[/2]) int8
-        vs = vs_ref[...].reshape(block_k)                    # (bk,) f32
+        e = _lut_exp(codes, m, lut_ref, table_f)             # (R, bk)
+        vs = vs_ref[...].reshape(block_k, 1)                 # (bk, 1) f32
         if kv_bits == 4:
-            v_deq = (_kv4_dequant(v, lv_ref[...].astype(jnp.float32))
-                     * vs[:, None])
+            v_deq = _kv4_dequant(v_ref, deq_ref[0],
+                                 lv_ref[...].astype(jnp.float32)) * vs
         else:
-            v_deq = v.astype(jnp.float32) * vs[:, None]
+            v = v_ref[...].reshape(block_k, v_ref.shape[-1])  # (bk, Dh) int8
+            v_deq = v.astype(jnp.float32) * vs
         acc = jax.lax.dot_general(     # (R, Dh)
-            e, v_deq, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            e, v_deq, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
         )
-        m_ref[...] = m[:, 0][None, None]
-        den_ref[...] = jnp.sum(e, axis=-1)[None, None]
+        m_ref[...] = m.reshape(m_ref.shape)
+        den_ref[...] = jnp.sum(e, axis=-1).reshape(den_ref.shape)
         acc_ref[...] = acc[None, None]
 
     @pl.when(jnp.logical_not(needed))
     def _skip():
-        iters_ref[0, 0] = 0
+        iters_ref[...] = jnp.zeros_like(iters_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG)
         den_ref[...] = jnp.zeros_like(den_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
@@ -159,7 +161,7 @@ def _decode_kernel(
 )
 def pim_decode_pallas(
     q_q: jax.Array,        # (BH, Sq, Dh) int8 (Sq == 1, or k+1 verify rows)
-    q_scale: jax.Array,    # (BH, Sq) f32
+    q_scale: jax.Array,    # (BH, Sq) float, cast to f32
     k_q: jax.Array,        # (BHkv, Sk, Dh) int8, or (Hkv, P, ps, Dh) paged
     k_scale: jax.Array,    # (BHkv, Sk) f32, or (Hkv, P, ps) paged
     v_q: jax.Array,        # like k_q
@@ -268,23 +270,25 @@ def pim_decode_pallas(
         [jnp.broadcast_to(q_off, (nb,)), jnp.broadcast_to(kvl, (nb,)),
          jnp.broadcast_to(ql, (nb,))]
     )                                                        # (3, nb)
+    # (8, 128) block tiling: scales and the per-row partials m, den as
+    # (1, n) lane rows, as in `pim_attention_pallas` (q scales cast to f32
+    # there too)
+    qsg = qsg.astype(jnp.float32)[:, None]
+    k_scale = k_scale[..., None, :]
+    v_scale = v_scale[..., None, :]
     if page_table is not None:
         # the index map turns the logical KV partition into a physical page:
         # clamped to the trash page for unallocated entries (the guarded
         # kernel body never reads the placeholder block)
-        kv_spec = pl.BlockSpec(
-            (1, 1, block_k, Dhk),
-            lambda b, k, s, t, h=hkv_per_b: (
-                jax.lax.rem(b, h), jnp.maximum(t[b // h, k], 0), 0, 0),
-        )
-        kvs_spec = pl.BlockSpec(
-            (1, 1, block_k),
-            lambda b, k, s, t, h=hkv_per_b: (
-                jax.lax.rem(b, h), jnp.maximum(t[b // h, k], 0), 0),
-        )
+        def page_index(b, k, s, t, h=hkv_per_b):
+            return (jax.lax.rem(b, h), jnp.maximum(t[b // h, k], 0), 0, 0)
+        kv_spec = pl.BlockSpec((1, 1, block_k, Dhk), page_index)
+        scale_spec = pl.BlockSpec((1, 1, 1, block_k), page_index)
     else:
         kv_spec = pl.BlockSpec((1, block_k, Dhk), lambda b, k, s, t: (b, k, 0))
-        kvs_spec = pl.BlockSpec((1, block_k), lambda b, k, s, t: (b, k))
+        scale_spec = pl.BlockSpec((1, 1, block_k),
+                                  lambda b, k, s, t: (b, 0, k))
+    part_spec = pl.BlockSpec((1, 1, 1, r_pad), lambda b, k, s, t: (b, k, 0, 0))
     part_m, part_den, part_acc, iters = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -292,29 +296,38 @@ def pim_decode_pallas(
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, r_pad, Dh), lambda b, k, s, t: (b, 0, 0)),
-                pl.BlockSpec((1, r_pad), lambda b, k, s, t: (b, 0)),
+                pl.BlockSpec((1, 1, r_pad), lambda b, k, s, t: (b, 0, 0)),
                 kv_spec,
-                kvs_spec,
+                scale_spec,
                 kv_spec,
-                kvs_spec,
+                scale_spec,
                 pl.BlockSpec((256,), lambda b, k, s, t: (0,)),
                 pl.BlockSpec((16,), lambda b, k, s, t: (0,)),
             ],
             out_specs=[
-                pl.BlockSpec((1, 1, r_pad), lambda b, k, s, t: (b, k, 0)),
-                pl.BlockSpec((1, 1, r_pad), lambda b, k, s, t: (b, k, 0)),
+                part_spec,
+                part_spec,
                 pl.BlockSpec((1, 1, r_pad, Dh), lambda b, k, s, t: (b, k, 0, 0)),
-                pl.BlockSpec((1, 1), lambda b, k, s, t: (b, k)),
+                pl.BlockSpec((1, 1, 1, 1), lambda b, k, s, t: (b, k, 0, 0)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((r_pad, block_k), jnp.float32),     # LUT exp
+                # 4-bit only: the dequantized K, then V, page
+                *([pltpu.VMEM((block_k, Dh), jnp.float32)]
+                  if kv_bits == 4 else []),
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((BHkv, n_k_blocks, r_pad), jnp.float32),
-            jax.ShapeDtypeStruct((BHkv, n_k_blocks, r_pad), jnp.float32),
+            jax.ShapeDtypeStruct((BHkv, n_k_blocks, 1, r_pad), jnp.float32),
+            jax.ShapeDtypeStruct((BHkv, n_k_blocks, 1, r_pad), jnp.float32),
             jax.ShapeDtypeStruct((BHkv, n_k_blocks, r_pad, Dh), jnp.float32),
-            jax.ShapeDtypeStruct((BHkv, n_k_blocks), jnp.int32),
+            jax.ShapeDtypeStruct((BHkv, n_k_blocks, 1, 1), jnp.int32),
         ],
         interpret=interpret,
     )(scalars, pt, qg, qsg, k_q, k_scale, v_q, v_scale, table, levels)
+    part_m = part_m[:, :, 0]
+    part_den = part_den[:, :, 0]
+    iters = iters.reshape(BHkv, n_k_blocks)
 
     # ---- stage 2: combine partitions in the LUT domain ---------------------
     # Rescale each partition to the global max with exp(-d*s) = table[d]/2^frac

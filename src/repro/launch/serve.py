@@ -33,7 +33,9 @@ bit-identical continuation streams.
 from __future__ import annotations
 
 import argparse
+import os
 import time
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -45,7 +47,19 @@ from repro.models.model_zoo import build_model
 from repro.runtime import serve_lib, sharding as sh
 
 
+def enable_compile_cache():
+    """Keep compiled programs across processes: in $JAX_COMPILATION_CACHE_DIR
+    when it is set (jax reads the variable itself), else at the one fixed
+    `.jax_cache` directory of the checkout.  The path is part of the cache
+    key, so it must not move between runs."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        root = Path(__file__).resolve().parents[3]
+        jax.config.update("jax_compilation_cache_dir",
+                          str(root / ".jax_cache"))
+
+
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -279,7 +293,10 @@ def main(argv=None):
         mode += f"+kv{cfg.kv_bits}"
     if args.speculate:
         mode += f"+speculative({args.draft_mode},k={args.draft_len})"
-    print(f"[serve] arch={cfg.name} attn={cfg.attn_impl} mode={mode} "
+    dev = jax.devices()[0]
+    print(f"[serve] device={dev.platform}:{dev.device_kind}"
+          f"x{jax.device_count()} "
+          f"arch={cfg.name} attn={cfg.attn_impl} mode={mode} "
           f"temp={args.temperature} top_k={args.top_k} top_p={args.top_p} "
           f"generated {out.shape} in {dt:.2f}s "
           f"({toks / dt:.1f} tok/s incl. prefill+compile)")

@@ -21,15 +21,6 @@ import jax
 import jax.numpy as jnp
 
 
-def _axis_size(name) -> int:
-    """Static size of a mapped axis, across jax versions: `jax.lax.axis_size`
-    (new) or `jax.core.axis_frame`, which returns the size directly (0.4.x)."""
-    if hasattr(jax.lax, "axis_size"):
-        return int(jax.lax.axis_size(name))
-    frame = jax.core.axis_frame(name)
-    return int(getattr(frame, "size", frame))
-
-
 def init_residual(params):
     return jax.tree.map(lambda p: jnp.zeros_like(p, jnp.float32), params)
 
@@ -60,7 +51,7 @@ def allreduce_compressed(grads, residuals, axis_name) -> Tuple[Any, Any]:
     names = (axis_name,) if isinstance(axis_name, str) else tuple(axis_name)
     n = 1
     for a in names:
-        n *= _axis_size(a)          # static under shard_map
+        n *= jax.lax.axis_size(a)   # static under shard_map
 
     def leaf(g, r):
         shape = g.shape

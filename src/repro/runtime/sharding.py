@@ -20,7 +20,6 @@ from typing import Any, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.configs.base import ModelConfig
 
@@ -329,9 +328,9 @@ def moe_shard_map(params, xf: jax.Array, cfg: ModelConfig, mesh: Mesh):
             aux = jax.lax.pmean(aux, reduce_axes)
         return y, aux
 
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(in_param_specs, tok_spec),
         out_specs=(tok_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )(params, xf)

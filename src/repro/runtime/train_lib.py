@@ -146,7 +146,6 @@ def _compressed_grads(accumulate, params, batch, residual, mesh: Mesh):
     all-gather from optim.compression.  Pure-DP scope: the compression path
     trades TP/FSDP for cheap DP collectives (EXPERIMENTS.md §Perf).
     """
-    from jax.experimental.shard_map import shard_map
     from repro.optim import compression
     ba = sh.batch_axes(mesh)
     if not ba:
@@ -160,10 +159,10 @@ def _compressed_grads(accumulate, params, batch, residual, mesh: Mesh):
 
     rep = jax.tree.map(lambda _: P(), params)
     bspec = jax.tree.map(lambda _: P(ba), batch)
-    g2, r2, loss = shard_map(
+    g2, r2, loss = jax.shard_map(
         local, mesh=mesh,
         in_specs=(rep, bspec, rep),
         out_specs=(rep, rep, P()),
-        check_rep=False,
+        check_vma=False,
     )(params, batch, residual)
     return g2, r2, {"loss": loss}
