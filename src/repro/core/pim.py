@@ -133,16 +133,21 @@ def pim_linear_apply(params, x: jax.Array, cfg: PIMConfig, enabled: bool = True)
     """Apply a linear layer, through the PIM behavioral model if `enabled`.
 
     Accepts either QAT params {"w": fp} or deployed params {"w_q", "w_scale"}.
+    Its ops carry the name scope `pim_linear` (HLO `op_name`
+    `.../pim_linear/...`), by which a profiler trace finds the linears'
+    device time.
     """
-    if "w_q" in params:
-        y = pim_matmul(x, params["w_q"], params["w_scale"], cfg, out_dtype=x.dtype)
-    elif enabled:
-        y = _pim_linear_core(x, params["w"].astype(x.dtype), cfg)
-    else:
-        y = x @ params["w"].astype(x.dtype)
-    if "b" in params:
-        y = y + params["b"].astype(y.dtype)  # digital-domain adder (qwen2 bias)
-    return y
+    with jax.named_scope("pim_linear"):
+        if "w_q" in params:
+            y = pim_matmul(x, params["w_q"], params["w_scale"], cfg,
+                           out_dtype=x.dtype)
+        elif enabled:
+            y = _pim_linear_core(x, params["w"].astype(x.dtype), cfg)
+        else:
+            y = x @ params["w"].astype(x.dtype)
+        if "b" in params:
+            y = y + params["b"].astype(y.dtype)  # digital-domain adder (qwen2 bias)
+        return y
 
 
 def deploy_params(params, cfg: PIMConfig):

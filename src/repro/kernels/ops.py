@@ -90,12 +90,14 @@ def kernel_attention_layout(q: jax.Array, cache: KVCache,
 def paged_kernel_layout(pool: PagedKVCache):
     """(P, page_size, Hkv, Dh) pool -> the head-major page-pool layout the
     page-table-aware kernels take: (Hkv, P, page_size, Dh) K/V with
-    (Hkv, P, page_size) scales."""
-    k_q = pool.k_q.transpose(2, 0, 1, 3)
-    v_q = pool.v_q.transpose(2, 0, 1, 3)
-    ks = pool.k_scale.transpose(2, 0, 1)
-    vs = pool.v_scale.transpose(2, 0, 1)
-    return k_q, ks, v_q, vs
+    (Hkv, P, page_size) scales.  Its ops carry the name scope `kv_relayout`,
+    by which a profiler trace finds the relayout's device time."""
+    with jax.named_scope("kv_relayout"):
+        k_q = pool.k_q.transpose(2, 0, 1, 3)
+        v_q = pool.v_q.transpose(2, 0, 1, 3)
+        ks = pool.k_scale.transpose(2, 0, 1)
+        vs = pool.v_scale.transpose(2, 0, 1)
+        return k_q, ks, v_q, vs
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))

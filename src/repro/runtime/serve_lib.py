@@ -642,6 +642,16 @@ DEFER = object()
 """Sentinel: admission must wait for the wave in flight to publish its
 prefix-directory entries (distinct from None == pool full)."""
 
+# Host spans of one `Scheduler.step`, on the profiler's own clock (a no-op
+# unless a trace is running): `sched.admit` (shedding, admission with,
+# without mixed steps, its prefill wave, fault hooks), then per dispatch
+# path `sched.plan` (page planning and the host arrays of the step),
+# `sched.dispatch` (argument transfers and the jitted call; arguments name
+# the program and its shape), `sched.readback` (reads of its outputs) and
+# `sched.commit` (token bookkeeping and retirement), and a last
+# `sched.commit` for the step's closing hooks.
+_span = jax.profiler.TraceAnnotation
+
 
 class Request:
     """One generation request tracked by the Scheduler.
@@ -1907,56 +1917,69 @@ class Scheduler:
     def _decode(self, emitted: Dict[int, List[int]]):
         if not self.active.any():
             return
-        run = self._plan_decode_run(self.decode_chunk)
-        if not run.any():
-            return
-        fn = make_ragged_decode_fn(self.model, self.decode_chunk,
-                                   self.temperature, self.top_k,
-                                   self.eos_id, self.max_len, self.top_p)
-        # stalled rows advertise length 0 for the whole chunk (writes are
-        # trash-routed, attention runs zero KV partitions — genuinely free,
-        # not just discarded) and have ALL their state restored host-side
-        rids, gens = self._slot_rids_gens()
-        self.model_steps += self.decode_chunk
-        args = (self.params, jnp.asarray(self.cur_tok), self.cache,
-                jnp.asarray(self.lengths * run), jnp.asarray(run),
-                jnp.asarray(self.remaining), jnp.asarray(rids),
-                jnp.asarray(gens), self.key,
-                jnp.asarray(self._poison_mask & run))
-        if self.paged:
-            out = fn(*args, jnp.asarray(self.page_table))
-        else:
-            out = fn(*args)
-        tok, self.cache, lengths, active, remaining, toks, em, pois = out
-        stalled = self.active & ~run
-        self.cur_tok = np.where(run, np.array(tok), self.cur_tok)
-        self.lengths = np.where(run, np.array(lengths), self.lengths)
-        self.active = np.array(active) | stalled
-        self.remaining = np.array(remaining)
-        toks = np.asarray(toks)                        # (chunk, B)
-        em = np.asarray(em)
-        pois = np.asarray(pois)
-        for b in range(self.B):
-            r = self.slot_req[b]
-            if r is None:
-                continue
-            step_toks = toks[em[:, b], b].tolist()
-            if step_toks:
-                r.tokens.extend(int(t) for t in step_toks)
-                emitted.setdefault(r.rid, []).extend(
-                    int(t) for t in step_toks)
-            if pois[b]:
-                # non-finite logits hit this row mid-scan: quarantine just
-                # this request (tokens before the poison were emitted and
-                # are kept); neighbors' rows are untouched — batch rows are
-                # independent, so their streams stay bit-identical
-                self.n_poisoned += 1
-                self._retire(b, status="poisoned", register=False)
-            elif not self.active[b] and not self.prefilling[b]:
-                # occupied, not decoding, not mid-chunked-prefill: the scan
-                # just finished it (prefilling slots are not in the scan —
-                # they retire through _finish_prefill's bookkeeping instead)
-                self._retire(b)
+        with _span("sched.plan"):
+            run = self._plan_decode_run(self.decode_chunk)
+            if not run.any():
+                return
+            fn = make_ragged_decode_fn(self.model, self.decode_chunk,
+                                       self.temperature, self.top_k,
+                                       self.eos_id, self.max_len, self.top_p)
+            # stalled rows advertise length 0 for the whole chunk (writes
+            # are trash-routed, attention runs zero KV partitions —
+            # genuinely free, not just discarded) and have ALL their state
+            # restored host-side
+            rids, gens = self._slot_rids_gens()
+            self.model_steps += self.decode_chunk
+            run_lengths = self.lengths * run
+            run_poison = self._poison_mask & run
+        with _span("sched.dispatch", program="decode_scan",
+                   chunk=self.decode_chunk):
+            args = (self.params, jnp.asarray(self.cur_tok), self.cache,
+                    jnp.asarray(run_lengths), jnp.asarray(run),
+                    jnp.asarray(self.remaining), jnp.asarray(rids),
+                    jnp.asarray(gens), self.key, jnp.asarray(run_poison))
+            if self.paged:
+                out = fn(*args, jnp.asarray(self.page_table))
+            else:
+                out = fn(*args)
+            tok, self.cache, lengths, active, remaining, toks, em, pois = out
+        with _span("sched.readback"):
+            tok = np.array(tok)
+            lengths = np.array(lengths)
+            active = np.array(active)
+            remaining = np.array(remaining)
+            toks = np.asarray(toks)                    # (chunk, B)
+            em = np.asarray(em)
+            pois = np.asarray(pois)
+        with _span("sched.commit"):
+            stalled = self.active & ~run
+            self.cur_tok = np.where(run, tok, self.cur_tok)
+            self.lengths = np.where(run, lengths, self.lengths)
+            self.active = active | stalled
+            self.remaining = remaining
+            for b in range(self.B):
+                r = self.slot_req[b]
+                if r is None:
+                    continue
+                step_toks = toks[em[:, b], b].tolist()
+                if step_toks:
+                    r.tokens.extend(int(t) for t in step_toks)
+                    emitted.setdefault(r.rid, []).extend(
+                        int(t) for t in step_toks)
+                if pois[b]:
+                    # non-finite logits hit this row mid-scan: quarantine
+                    # just this request (tokens before the poison were
+                    # emitted and are kept); neighbors' rows are untouched —
+                    # batch rows are independent, so their streams stay
+                    # bit-identical
+                    self.n_poisoned += 1
+                    self._retire(b, status="poisoned", register=False)
+                elif not self.active[b] and not self.prefilling[b]:
+                    # occupied, not decoding, not mid-chunked-prefill: the
+                    # scan just finished it (prefilling slots are not in the
+                    # scan — they retire through _finish_prefill's
+                    # bookkeeping instead)
+                    self._retire(b)
 
     # -- mixed prefill+decode steps -----------------------------------------
     def _finish_prefill(self, slot: int, tok0: int,
@@ -2031,40 +2054,45 @@ class Scheduler:
         device program is the SAME `make_paged_prefill_fn` an unchunked
         admission wave runs, at per-row chunk offsets — which is why chunked
         bytes and tokens are bit-identical to unchunked admission."""
-        chunks = self._plan_chunks()
-        if not chunks:
-            return
-        n = len(chunks)
-        L = self._bucket(max(e - s for _, s, e in chunks))
-        toks = np.zeros((n, L), np.int32)
-        for i, (b, s, e) in enumerate(chunks):
-            toks[i, : e - s] = self._pend[b][s:e]
-        slots = np.array([b for b, _, _ in chunks], np.int32)
-        offs = np.array([s for _, s, _ in chunks], np.int32)
-        lens = np.array([e - s for _, s, e in chunks], np.int32)
-        rids = np.array([self.slot_req[b].rid for b, _, _ in chunks],
-                        np.int32)
-        gens = np.array([len(self.slot_req[b].tokens)
-                         for b, _, _ in chunks], np.int32)
-        self.prefill_tokens_computed += int(lens.sum())
-        self.model_steps += 1
-        fn = make_paged_prefill_fn(self.model, n, L, self.temperature,
-                                   self.top_k, self.top_p)
-        self.cache, tok0, fin = fn(self.params, jnp.asarray(toks),
-                                   jnp.asarray(lens), self.cache,
-                                   jnp.asarray(self.page_table[slots]),
-                                   jnp.asarray(offs), jnp.asarray(rids),
-                                   jnp.asarray(gens), self.key)
-        tok0 = np.asarray(tok0)
-        fin = np.asarray(fin)
-        for i, (b, s, e) in enumerate(chunks):
-            self.lengths[b] = e
-            if e == len(self._pend[b]):
-                if fin[i]:
-                    self._finish_prefill(b, int(tok0[i]), emitted)
-                else:
-                    self.n_poisoned += 1
-                    self._retire(b, status="poisoned", register=False)
+        with _span("sched.plan"):
+            chunks = self._plan_chunks()
+            if not chunks:
+                return
+            n = len(chunks)
+            L = self._bucket(max(e - s for _, s, e in chunks))
+            toks = np.zeros((n, L), np.int32)
+            for i, (b, s, e) in enumerate(chunks):
+                toks[i, : e - s] = self._pend[b][s:e]
+            slots = np.array([b for b, _, _ in chunks], np.int32)
+            offs = np.array([s for _, s, _ in chunks], np.int32)
+            lens = np.array([e - s for _, s, e in chunks], np.int32)
+            rids = np.array([self.slot_req[b].rid for b, _, _ in chunks],
+                            np.int32)
+            gens = np.array([len(self.slot_req[b].tokens)
+                             for b, _, _ in chunks], np.int32)
+            self.prefill_tokens_computed += int(lens.sum())
+            self.model_steps += 1
+            fn = make_paged_prefill_fn(self.model, n, L, self.temperature,
+                                       self.top_k, self.top_p)
+            rows = self.page_table[slots]
+        with _span("sched.dispatch", program="prefill_wave", n=n, L=L):
+            self.cache, tok0, fin = fn(self.params, jnp.asarray(toks),
+                                       jnp.asarray(lens), self.cache,
+                                       jnp.asarray(rows), jnp.asarray(offs),
+                                       jnp.asarray(rids), jnp.asarray(gens),
+                                       self.key)
+        with _span("sched.readback"):
+            tok0 = np.asarray(tok0)
+            fin = np.asarray(fin)
+        with _span("sched.commit"):
+            for i, (b, s, e) in enumerate(chunks):
+                self.lengths[b] = e
+                if e == len(self._pend[b]):
+                    if fin[i]:
+                        self._finish_prefill(b, int(tok0[i]), emitted)
+                    else:
+                        self.n_poisoned += 1
+                        self._retire(b, status="poisoned", register=False)
 
     def _mixed_step_fused(self, emitted: Dict[int, List[int]]):
         """Fused mixed step: ONE (B, L) dispatch — every decoding slot that
@@ -2072,53 +2100,59 @@ class Scheduler:
         chunk, idle rows nothing.  Attention routes the two row classes
         through their unchunked kernels inside the one program
         (`blocks._mixed_attend` + the ragged-Q q_len early-outs)."""
-        run = self._plan_decode_run(1)
-        chunks = self._plan_chunks()
-        if not chunks and not run.any():
-            return
-        L = self._bucket(max([e - s for _, s, e in chunks] + [1]))
-        toks = np.zeros((self.B, L), np.int32)
-        offs = np.zeros(self.B, np.int32)
-        seq = np.zeros(self.B, np.int32)
-        dec = np.zeros(self.B, bool)
-        for b, s, e in chunks:
-            toks[b, : e - s] = self._pend[b][s:e]
-            offs[b] = s
-            seq[b] = e - s
-        for b in np.flatnonzero(run):
-            toks[b, 0] = self.cur_tok[b]
-            offs[b] = self.lengths[b]
-            seq[b] = 1
-            dec[b] = True
-        self.prefill_tokens_computed += sum(e - s for _, s, e in chunks)
-        self.model_steps += 1
-        rids, gens = self._slot_rids_gens()
-        fn = make_mixed_step_fn(self.model, self.B, L, self.temperature,
-                                self.top_k, self.top_p)
-        args = (self.params, jnp.asarray(toks), self.cache,
-                jnp.asarray(offs), jnp.asarray(seq), jnp.asarray(dec),
-                jnp.asarray(rids), jnp.asarray(gens), self.key,
-                jnp.asarray(self._poison_mask & (seq > 0)))
-        if self.paged:
-            self.cache, tok, fin = fn(*args, jnp.asarray(self.page_table))
-        else:
-            self.cache, tok, fin = fn(*args)
-        tok = np.asarray(tok)
-        fin = np.asarray(fin)
-        for b, s, e in chunks:
-            self.lengths[b] = e
-            if e == len(self._pend[b]):
+        with _span("sched.plan"):
+            run = self._plan_decode_run(1)
+            chunks = self._plan_chunks()
+            if not chunks and not run.any():
+                return
+            L = self._bucket(max([e - s for _, s, e in chunks] + [1]))
+            toks = np.zeros((self.B, L), np.int32)
+            offs = np.zeros(self.B, np.int32)
+            seq = np.zeros(self.B, np.int32)
+            dec = np.zeros(self.B, bool)
+            for b, s, e in chunks:
+                toks[b, : e - s] = self._pend[b][s:e]
+                offs[b] = s
+                seq[b] = e - s
+            for b in np.flatnonzero(run):
+                toks[b, 0] = self.cur_tok[b]
+                offs[b] = self.lengths[b]
+                seq[b] = 1
+                dec[b] = True
+            self.prefill_tokens_computed += sum(e - s for _, s, e in chunks)
+            self.model_steps += 1
+            rids, gens = self._slot_rids_gens()
+            fn = make_mixed_step_fn(self.model, self.B, L, self.temperature,
+                                    self.top_k, self.top_p)
+            poison = self._poison_mask & (seq > 0)
+        with _span("sched.dispatch", program="mixed", n=self.B, L=L):
+            args = (self.params, jnp.asarray(toks), self.cache,
+                    jnp.asarray(offs), jnp.asarray(seq), jnp.asarray(dec),
+                    jnp.asarray(rids), jnp.asarray(gens), self.key,
+                    jnp.asarray(poison))
+            if self.paged:
+                self.cache, tok, fin = fn(*args,
+                                          jnp.asarray(self.page_table))
+            else:
+                self.cache, tok, fin = fn(*args)
+        with _span("sched.readback"):
+            tok = np.asarray(tok)
+            fin = np.asarray(fin)
+        with _span("sched.commit"):
+            for b, s, e in chunks:
+                self.lengths[b] = e
+                if e == len(self._pend[b]):
+                    if fin[b]:
+                        self._finish_prefill(b, int(tok[b]), emitted)
+                    else:
+                        self.n_poisoned += 1
+                        self._retire(b, status="poisoned", register=False)
+            for b in np.flatnonzero(dec):
                 if fin[b]:
-                    self._finish_prefill(b, int(tok[b]), emitted)
+                    self._post_decode_token(b, int(tok[b]), emitted)
                 else:
                     self.n_poisoned += 1
                     self._retire(b, status="poisoned", register=False)
-        for b in np.flatnonzero(dec):
-            if fin[b]:
-                self._post_decode_token(b, int(tok[b]), emitted)
-            else:
-                self.n_poisoned += 1
-                self._retire(b, status="poisoned", register=False)
 
     def _mixed_step(self, emitted: Dict[int, List[int]]):
         """One mixed scheduler step — no slot ever waits for another slot's
@@ -2163,93 +2197,99 @@ class Scheduler:
         even that starves does pass 2 fall back to the regular
         evict-youngest path.  Speculation therefore never evicts a
         neighbor just to chase draft tokens."""
-        chunks = self._plan_chunks() if with_chunks else []
-        drafts: List[List[int]] = [[] for _ in range(self.B)]
-        karr = np.zeros(self.B, np.int32)
-        for b in np.flatnonzero(self.active):
-            drafts[b] = self._propose(int(b))
-            karr[b] = len(drafts[b])
-        run = self._plan_decode_run(1 + karr, evict_on_starve=False)
-        starved = self.active & ~run
-        if starved.any():
-            karr[starved] = 0
-            for b in np.flatnonzero(starved):
-                drafts[b] = []
-            run = self._plan_decode_run(1 + karr)
-        if not chunks and not run.any():
-            return
-        P = self.draft_len + 1
-        # rectangle width: P covers every verify row; only widen (to the
-        # prefill bucket) when a mixed-mode chunk actually rides along —
-        # _bucket(1) is the full prefill_bucket, which would make every
-        # chunkless spec step pay for 16 columns of masked padding
-        L = (max(P, self._bucket(max(e - s for _, s, e in chunks)))
-             if chunks else P)
-        toks = np.zeros((self.B, L), np.int32)
-        offs = np.zeros(self.B, np.int32)
-        seq = np.zeros(self.B, np.int32)
-        dec = np.zeros(self.B, bool)
-        for b, s, e in chunks:
-            toks[b, : e - s] = self._pend[b][s:e]
-            offs[b] = s
-            seq[b] = e - s
-        for b in np.flatnonzero(run):
-            k = int(karr[b])
-            toks[b, 0] = self.cur_tok[b]
-            if k:
-                toks[b, 1: 1 + k] = drafts[b]
-            offs[b] = self.lengths[b]
-            seq[b] = 1 + k
-            dec[b] = True
-        self.prefill_tokens_computed += sum(e - s for _, s, e in chunks)
-        self.model_steps += 1
-        self.n_spec_steps += 1
-        rids, gens = self._slot_rids_gens()
-        fn = make_spec_step_fn(self.model, self.B, L, P, self.temperature,
-                               self.top_k, self.top_p)
-        args = (self.params, jnp.asarray(toks), self.cache,
-                jnp.asarray(offs), jnp.asarray(seq), jnp.asarray(dec),
-                jnp.asarray(rids), jnp.asarray(gens), self.key,
-                jnp.asarray(self._poison_mask & (seq > 0)))
-        if self.paged:
-            self.cache, out, n_emit, fin = fn(*args,
-                                              jnp.asarray(self.page_table))
-        else:
-            self.cache, out, n_emit, fin = fn(*args)
-        out = np.asarray(out)
-        n_emit = np.asarray(n_emit)
-        fin = np.asarray(fin)
-        for b, s, e in chunks:
-            self.lengths[b] = e
-            if e == len(self._pend[b]):
-                if fin[b]:
-                    self._finish_prefill(b, int(out[b, 0]), emitted)
-                else:
+        with _span("sched.plan"):
+            chunks = self._plan_chunks() if with_chunks else []
+            drafts: List[List[int]] = [[] for _ in range(self.B)]
+            karr = np.zeros(self.B, np.int32)
+            for b in np.flatnonzero(self.active):
+                drafts[b] = self._propose(int(b))
+                karr[b] = len(drafts[b])
+            run = self._plan_decode_run(1 + karr, evict_on_starve=False)
+            starved = self.active & ~run
+            if starved.any():
+                karr[starved] = 0
+                for b in np.flatnonzero(starved):
+                    drafts[b] = []
+                run = self._plan_decode_run(1 + karr)
+            if not chunks and not run.any():
+                return
+            P = self.draft_len + 1
+            # rectangle width: P covers every verify row; only widen (to the
+            # prefill bucket) when a mixed-mode chunk actually rides along —
+            # _bucket(1) is the full prefill_bucket, which would make every
+            # chunkless spec step pay for 16 columns of masked padding
+            L = (max(P, self._bucket(max(e - s for _, s, e in chunks)))
+                 if chunks else P)
+            toks = np.zeros((self.B, L), np.int32)
+            offs = np.zeros(self.B, np.int32)
+            seq = np.zeros(self.B, np.int32)
+            dec = np.zeros(self.B, bool)
+            for b, s, e in chunks:
+                toks[b, : e - s] = self._pend[b][s:e]
+                offs[b] = s
+                seq[b] = e - s
+            for b in np.flatnonzero(run):
+                k = int(karr[b])
+                toks[b, 0] = self.cur_tok[b]
+                if k:
+                    toks[b, 1: 1 + k] = drafts[b]
+                offs[b] = self.lengths[b]
+                seq[b] = 1 + k
+                dec[b] = True
+            self.prefill_tokens_computed += sum(e - s for _, s, e in chunks)
+            self.model_steps += 1
+            self.n_spec_steps += 1
+            rids, gens = self._slot_rids_gens()
+            fn = make_spec_step_fn(self.model, self.B, L, P, self.temperature,
+                                   self.top_k, self.top_p)
+            poison = self._poison_mask & (seq > 0)
+        with _span("sched.dispatch", program="spec", n=self.B, L=L):
+            args = (self.params, jnp.asarray(toks), self.cache,
+                    jnp.asarray(offs), jnp.asarray(seq), jnp.asarray(dec),
+                    jnp.asarray(rids), jnp.asarray(gens), self.key,
+                    jnp.asarray(poison))
+            if self.paged:
+                self.cache, out, n_emit, fin = fn(*args,
+                                                  jnp.asarray(self.page_table))
+            else:
+                self.cache, out, n_emit, fin = fn(*args)
+        with _span("sched.readback"):
+            out = np.asarray(out)
+            n_emit = np.asarray(n_emit)
+            fin = np.asarray(fin)
+        with _span("sched.commit"):
+            for b, s, e in chunks:
+                self.lengths[b] = e
+                if e == len(self._pend[b]):
+                    if fin[b]:
+                        self._finish_prefill(b, int(out[b, 0]), emitted)
+                    else:
+                        self.n_poisoned += 1
+                        self._retire(b, status="poisoned", register=False)
+            for b in np.flatnonzero(dec):
+                if not fin[b]:
+                    # poisoned verify row: nothing from this step is
+                    # emitted — the request retires alone, draft accounting
+                    # untouched
                     self.n_poisoned += 1
                     self._retire(b, status="poisoned", register=False)
-        for b in np.flatnonzero(dec):
-            if not fin[b]:
-                # poisoned verify row: nothing from this step is emitted —
-                # the request retires alone, draft accounting untouched
-                self.n_poisoned += 1
-                self._retire(b, status="poisoned", register=False)
-                continue
-            r = self.slot_req[b]
-            k = int(karr[b])
-            m = int(n_emit[b])
-            if k:
-                a = m - 1
-                self.spec_proposed += k
-                self.spec_accepted += a
-                self.spec_rejected += k - a
-                if a == k:
-                    r.spec_k = min(self.draft_len, r.spec_k + 1)
-                elif a == 0:
-                    r.spec_k = max(1, r.spec_k // 2)
-            for j in range(m):
-                self._post_decode_token(b, int(out[b, j]), emitted)
-                if self.slot_req[b] is None:
-                    break      # retired mid-prefix: later tokens discarded
+                    continue
+                r = self.slot_req[b]
+                k = int(karr[b])
+                m = int(n_emit[b])
+                if k:
+                    a = m - 1
+                    self.spec_proposed += k
+                    self.spec_accepted += a
+                    self.spec_rejected += k - a
+                    if a == k:
+                        r.spec_k = min(self.draft_len, r.spec_k + 1)
+                    elif a == 0:
+                        r.spec_k = max(1, r.spec_k // 2)
+                for j in range(m):
+                    self._post_decode_token(b, int(out[b, j]), emitted)
+                    if self.slot_req[b] is None:
+                        break      # retired mid-prefix: later tokens discarded
 
     # -- SLA degradation ladder ---------------------------------------------
     def _effective_chunk_budget(self) -> int:
@@ -2549,7 +2589,8 @@ class Scheduler:
         one fused decode chunk-scan; retire as slots finish.  Returns the
         tokens generated this round, keyed by request id.  Fault-injection
         hooks and the per-step invariant audit (`REPRO_AUDIT=1` /
-        `audit_every_step=True`) run here."""
+        `audit_every_step=True`) run here.  Each phase is a profiler span
+        (`sched.admit`, ..., `sched.commit`; see `_span`)."""
         emitted: Dict[int, List[int]] = {}
         self._step_idx += 1
         if (self._faults is not None
@@ -2557,38 +2598,40 @@ class Scheduler:
             # before any work this step — the last periodic snapshot is the
             # newest durable state, exactly like a real mid-trace crash
             raise CrashInjected(f"injected crash at step {self._step_idx}")
-        self._shed_stale()
-        self._shed_admitted()
-        self._queue_depths.append(len(self.queue))
-        self._ladder_update()
-        if (self.ladder_level >= 3
-                and any(r is not None for r in self.slot_req)):
-            # deepest rung: pause admission while residents drain.  Never
-            # with ALL slots empty — then admission must run or nothing
-            # would ever drain the queue (livelock)
-            self.ladder_paused_steps += 1
-        else:
-            self._admit(emitted)
-        if (self._faults is not None and self.active.any()
-                and self._faults.force_evict(self._step_idx)):
-            self._evict(self._eviction_victim())
-        if (self._faults is not None and self._victim
-                and self._faults.bitflip_spilled_page(self._step_idx)):
-            self._bitflip_victim_page()
-        occupied = self.active | self.prefilling
-        if (self._faults is not None and occupied.any()
-                and self._faults.poison_nan(self._step_idx)):
-            # poison the occupied slot with the lowest rid — deterministic
-            # across runs, so the chaos suite can diff against a run
-            # without that request.  Mid-prefill slots count (mixed-steps
-            # chunking keeps them `prefilling`, not `active`, for several
-            # steps) and the mark is STICKY (cleared only when the slot is
-            # vacated): a victim whose logits nothing samples at the fault
-            # step retires at its next sampled logits instead of silently
-            # shrugging the fault off
-            victim = min((int(b) for b in np.flatnonzero(occupied)),
-                         key=lambda b: self.slot_req[b].rid)
-            self._poison_mask[victim] = True
+        with _span("sched.admit"):
+            self._shed_stale()
+            self._shed_admitted()
+            self._queue_depths.append(len(self.queue))
+            self._ladder_update()
+            if (self.ladder_level >= 3
+                    and any(r is not None for r in self.slot_req)):
+                # deepest rung: pause admission while residents drain.  Never
+                # with ALL slots empty — then admission must run or nothing
+                # would ever drain the queue (livelock)
+                self.ladder_paused_steps += 1
+            else:
+                self._admit(emitted)
+            if (self._faults is not None and self.active.any()
+                    and self._faults.force_evict(self._step_idx)):
+                self._evict(self._eviction_victim())
+            if (self._faults is not None and self._victim
+                    and self._faults.bitflip_spilled_page(self._step_idx)):
+                self._bitflip_victim_page()
+            occupied = self.active | self.prefilling
+            if (self._faults is not None and occupied.any()
+                    and self._faults.poison_nan(self._step_idx)):
+                # poison the occupied slot with the lowest rid —
+                # deterministic across runs, so the chaos suite can diff
+                # against a run without that request.  Mid-prefill slots
+                # count (mixed-steps chunking keeps them `prefilling`, not
+                # `active`, for several steps) and the mark is STICKY
+                # (cleared only when the slot is vacated): a victim whose
+                # logits nothing samples at the fault step retires at its
+                # next sampled logits instead of silently shrugging the
+                # fault off
+                victim = min((int(b) for b in np.flatnonzero(occupied)),
+                             key=lambda b: self.slot_req[b].rid)
+                self._poison_mask[victim] = True
         if self.speculate and self.ladder_level < 1:
             if (self.mixed_steps and self.prefilling.any()
                     and self.mixed_dispatch == "paired"):
@@ -2602,18 +2645,19 @@ class Scheduler:
             self._mixed_step(emitted)
         else:
             self._decode(emitted)
-        if self.paged:
-            self.peak_pages_in_use = max(self.peak_pages_in_use,
-                                         self.pages_in_use())
-        if (self._faults is not None and self.paged
-                and self._faults.corrupt_refcount(self._step_idx)):
-            self._corrupt_and_detect()
-        if self._audit_every:
-            self.audit()
-        if (self.snapshot_every
-                and self._step_idx % self.snapshot_every == 0):
-            self.snapshot()
-        self._sample_tbt()
+        with _span("sched.commit"):
+            if self.paged:
+                self.peak_pages_in_use = max(self.peak_pages_in_use,
+                                             self.pages_in_use())
+            if (self._faults is not None and self.paged
+                    and self._faults.corrupt_refcount(self._step_idx)):
+                self._corrupt_and_detect()
+            if self._audit_every:
+                self.audit()
+            if (self.snapshot_every
+                    and self._step_idx % self.snapshot_every == 0):
+                self.snapshot()
+            self._sample_tbt()
         return emitted
 
     # -- invariant audit ----------------------------------------------------
