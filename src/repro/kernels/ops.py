@@ -202,10 +202,12 @@ def pim_paged_flash_attention(
     force_decode_kernel: bool = False,
 ) -> jax.Array:
     """Fused PIM attention over the paged KV pool: both kernels walk the
-    slot's page-table row instead of a contiguous cache (pages are the
-    split-K partitions of the decode grid; the prefill kernel's KV axis runs
-    over table entries).  Bit-identical to `pim_flash_attention` over a
-    dense cache holding the same tokens with block_k == page_size.
+    slot's page-table row instead of a contiguous cache.  Every KV
+    partition of the decode kernel IS one page; a grid step of it fetches
+    a block of the row's pages by async copies (see `pim_decode`).  The
+    prefill kernel's KV axis runs over table entries, one page a step.
+    Bit-identical to `pim_flash_attention` over a dense cache holding the
+    same tokens with block_k == page_size.
 
     `q_len` is the optional (B,) ragged-Q vector (valid query rows per slot;
     0 = the row contributes nothing to this launch and costs zero compute).

@@ -197,6 +197,85 @@ def test_paged_decode_page_boundary_steps():
                                              Hkv * 2])
 
 
+# (lens, max_len, q_len, sq, head_dim, kv_bits, share): blocks of 2 pages of
+# 16 below, so a 5-entry table ends in a ragged block
+_BLOCK_WALK_CASES = {
+    "ragged_table_width": ([80, 33, 1], 80, None, 1, 128, 8, False),
+    "kv_len_block_edge_mid_zero": ([32, 40, 0, 64], 80, None, 1, 128, 8,
+                                   False),
+    "q_len_zero_rows": ([50, 70, 20], 80, [1, 0, 1], 1, 128, 8, False),
+    "shared_page_ids": ([40, 24, 16], 64, None, 1, 128, 8, True),
+    "verify_sq5": ([50, 33, 7, 64], 80, [5, 3, 5, 0], 5, 128, 8, False),
+    "kv4_one_page_walk": ([50, 33, 0], 80, None, 1, 128, 4, False),
+    "kv4_wide_heads": ([40, 17], 48, None, 1, 256, 4, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_BLOCK_WALK_CASES))
+def test_paged_decode_block_walk_bitexact(case, monkeypatch):
+    """The paged decode kernel's block walk (a grid step fetches a block
+    of pages by async copies) is bit-equal to the dense kernel at
+    block_k == page_size, with the same map of partitions run: ragged last
+    blocks, kv_len at and inside block edges, empty rows, q_len 0 rows,
+    scattered and shared page ids, verify rows.  Pools whose stored width
+    is not whole lanes (4-bit pages of Dh/2 = 64 bytes) keep the one-page
+    walk; 4-bit pages of 128 bytes take blocks too."""
+    from repro.kernels import pim_decode as pd
+    lens, max_len, q_len, sq, Dh, kv_bits, share = _BLOCK_WALK_CASES[case]
+    B, H, Hkv, ps = len(lens), 4, 2, 16
+    # 2 pages per block; traces made at another block size are dropped
+    monkeypatch.setattr(pd, "_PAGED_BLOCK_TOKENS", 2 * ps)
+    pim_decode_pallas.clear_cache()
+    blocks = []
+    real = pd._decode_block_kernel
+
+    def spy(*args, **kw):
+        blocks.append(kw["ppb"])
+        return real(*args, **kw)
+
+    monkeypatch.setattr(pd, "_decode_block_kernel", spy)
+    seed = sum(map(ord, case))
+    key = jax.random.PRNGKey(seed)
+    k = jax.random.normal(jax.random.fold_in(key, 1),
+                          (B, max_len, Hkv, Dh)) * 0.5
+    v = jax.random.normal(jax.random.fold_in(key, 2),
+                          (B, max_len, Hkv, Dh)) * 0.5
+    if share:   # row 1 starts with row 0's first page, as a shared prefix
+        k = k.at[1, :ps].set(k[0, :ps])
+        v = v.at[1, :ps].set(v[0, :ps])
+    lens_a = jnp.asarray(lens, jnp.int32)
+    zeros = jnp.zeros(B, jnp.int32)
+    dense = attn.cache_write_ragged(
+        attn.init_kv_cache(B, max_len, Hkv, Dh, ragged=True, kv_bits=kv_bits),
+        k, v, zeros, PIM, seq_lens=lens_a)
+    pt, P = _random_table(np.random.RandomState(seed), lens, ps,
+                          max_len // ps)
+    pool = attn.paged_cache_write(
+        attn.init_paged_kv_cache(P, ps, Hkv, Dh, kv_bits=kv_bits),
+        k, v, zeros, PIM, jnp.asarray(pt), seq_lens=lens_a)
+    if share:
+        pt[1, 0] = pt[0, 0]
+    ql = (jnp.asarray(q_len, jnp.int32) if q_len is not None
+          else jnp.ones(B, jnp.int32))
+    offs = jnp.maximum(lens_a - jnp.maximum(ql, 1), 0)
+    q = jax.random.normal(jax.random.fold_in(key, 3), (B, sq, H, Dh)) * 0.5
+
+    o_d, it_d = pim_decode_pallas(
+        *ops.kernel_attention_layout(q, dense), offs, dense.length,
+        block_k=ps, interpret=True, return_iters=True, q_len=ql)
+    assert blocks == []                      # the dense walk is untouched
+    q_q, qs = ops._q_kernel_layout(q, PIM.input_bits)
+    o_p, it_p = pim_decode_pallas(
+        q_q, qs, *ops.paged_kernel_layout(pool), offs, lens_a,
+        interpret=True, return_iters=True, page_table=jnp.asarray(pt),
+        q_len=ql)
+    pim_decode_pallas.clear_cache()
+    assert blocks == ([2] if kv_bits == 8 else [])
+    np.testing.assert_array_equal(np.asarray(o_d), np.asarray(o_p))
+    np.testing.assert_array_equal(np.asarray(it_d), np.asarray(it_p))
+    assert np.asarray(it_p).sum() > 0 or not any(lens)
+
+
 # ---------------------------------------------------------------------------
 # cache_write_ragged overflow (satellite): debug check + truncation contract
 # ---------------------------------------------------------------------------

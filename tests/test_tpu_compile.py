@@ -5,7 +5,9 @@ not attached, and refuses what the chip would refuse (block shapes off the
 (8, 128) tiling, primitives Mosaic cannot lower, scoped VMEM overruns).
 Interpret mode on the CPU checks none of that.  Shapes are internlm2-1.8b's
 attention widths (H 16, Hkv 8, head_dim 128) with the serving defaults:
-block_k 256 dense, pages of 16, verify rows of 5 queries.
+block_k 256 dense, pages of 16, verify rows of 5 queries.  The paged
+decode cases cover both of its walks: blocks of 8-bit pages fetched by
+async copies, and 4-bit pages (64 bytes wide) one per grid step.
 """
 import os
 import re
@@ -79,6 +81,8 @@ def _operands(chip, sq, kv_bits, paged):
     ("verify", 5, 8, False),
     ("verify", 5, 8, True),
     ("verify", 5, 4, False),
+    ("decode", 1, 4, True),
+    ("verify", 5, 4, True),
 ])
 def test_kernel_compiles_for_v5e(one_chip, kind, sq, kv_bits, paged):
     fn = pim_attention_pallas if kind == "prefill" else pim_decode_pallas
