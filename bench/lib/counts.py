@@ -61,23 +61,14 @@ def least_seconds(ops: float, nbytes: float, device_kind: str,
     return max(ops / p[peak], nbytes / p["hbm_bytes_per_s"])
 
 
-def matmul_params(cfg: Dict) -> int:
-    """Weights of every per-token matmul of the layer stack (q, k, v, o
-    and the three SwiGLU projections); the vocabulary head is apart."""
-    d, f = cfg["hidden_size"], cfg["intermediate_size"]
-    h, hkv, dh = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                  cfg["head_dim"])
-    per_layer = d * dh * (2 * h + 2 * hkv) + 3 * d * f
-    return cfg["num_hidden_layers"] * per_layer
-
-
-def model_ops(cfg: Dict, tokens: int, logit_rows: int,
+def model_ops(cfg: Dict, layout, tokens: int, logit_rows: int,
               attn_keys: float) -> float:
-    """Model operations of `tokens` processed (2 per weight per token),
-    `logit_rows` vocabulary-head rows, and attention over `attn_keys`
-    (query, key) pairs summed over tokens: 4 * H * Dh per pair per layer."""
+    """Model operations of `tokens` processed (the linears' ops of the
+    cell's layout, `layout.linear_work`), `logit_rows` vocabulary-head rows,
+    and attention over `attn_keys` (query, key) pairs summed over tokens:
+    4 * H * Dh per pair per attention layer."""
     d, v = cfg["hidden_size"], cfg["vocab_size"]
-    h, dh, n_l = (cfg["num_attention_heads"], cfg["head_dim"],
-                  cfg["num_hidden_layers"])
-    return (2.0 * matmul_params(cfg) * tokens + 2.0 * d * v * logit_rows
-            + 4.0 * h * dh * n_l * attn_keys)
+    h, dh = cfg["num_attention_heads"], cfg["head_dim"]
+    linear_ops, _ = layout.linear_work(cfg, tokens)
+    return (linear_ops + 2.0 * d * v * logit_rows
+            + 4.0 * h * dh * layout.attention_layers(cfg) * attn_keys)

@@ -28,7 +28,7 @@ from typing import Dict, List, Optional, Tuple
 
 from bench.lib import check, latency, spec
 from bench.lib.loop import ServingLoop
-from bench.lib.model import layer_weights, make_params, model_config
+from bench.lib.model import make_params
 from bench.lib.traffic import Traffic
 
 SCHEDULER_OPTIONS = ("max_batch_slots", "max_len", "page_size", "num_pages",
@@ -174,12 +174,13 @@ class _Tracer:
             self.done = True
 
 
-def per_layer(cell: spec.Cell, red, steps, device_kind: str
+def per_layer(cell: spec.Cell, layout, red, steps, device_kind: str
               ) -> Dict[str, Dict]:
     """Each per-layer metric of the cell, from its own reader; a reader
     that finds nothing to read leaves its metric out."""
     from bench.lib import counts
     ctx = SimpleNamespace(reduction=red, steps=steps, config=cell.config,
+                          layout=layout,
                           server=cell.traffic["server"],
                           device_kind=device_kind,
                           peaks=counts.peaks(device_kind))
@@ -191,15 +192,17 @@ def per_layer(cell: spec.Cell, red, steps, device_kind: str
     return out
 
 
-def build_server(cell: spec.Cell, seed: int, overrides: Optional[Dict]):
-    """The program's model and scheduler for the cell, with weights made
-    from `seed`, and every step program the cell uses compiled."""
+def build_server(cell: spec.Cell, layout, seed: int,
+                 overrides: Optional[Dict]):
+    """The program's model and scheduler for the cell, by the cell's
+    layout, with weights made from `seed`, and every step program the cell
+    uses compiled."""
     from repro.models.model_zoo import build_model
     from repro.runtime.serve_lib import Scheduler
 
-    cfg = model_config(cell.config, **(overrides or {}))
+    cfg = layout.program_config(cell.config, **(overrides or {}))
     model = build_model(cfg)
-    params = make_params(model, seed)
+    params = make_params(model, seed, layout.WEIGHT_RULES)
     server = cell.traffic["server"]
     sched = Scheduler(model, params, eos_id=None, temperature=0.0,
                       **{k: server[k] for k in SCHEDULER_OPTIONS
@@ -229,7 +232,8 @@ def run_cell(root, workload: str, seed: int, seconds: float, trace: bool,
         enable_compile_cache(root)
     clock = CompileClock()
 
-    cfg, params, sched, n_warm = build_server(cell, seed, overrides)
+    layout = spec.layout_module(root, cell.config)
+    cfg, params, sched, n_warm = build_server(cell, layout, seed, overrides)
     server = cell.traffic["server"]
     traffic = Traffic(cell.traffic, seed, cfg.vocab_size, server["max_len"])
     loop = ServingLoop(sched, traffic)
@@ -312,10 +316,7 @@ def run_cell(root, workload: str, seed: int, seconds: float, trace: bool,
     del sched
     gc.collect()
     ref = spec.reference_module(root, cell.config)
-    weights = {"embed": params["embed"]["table"],
-               "head": params["unembed"]["table"],
-               "final_norm": params["final_norm"]["scale"],
-               "layer": lambda i: layer_weights(params, i)}
+    weights = layout.weight_views(params, cell.config)
     chk = cell.traffic["check"]
     sample = check.pick(recs, int(chk["requests"]), seed)
     t_chk = time.perf_counter()
@@ -342,7 +343,8 @@ def run_cell(root, workload: str, seed: int, seconds: float, trace: bool,
     else:
         if red is None:
             raise RuntimeError("the traced run produced no trace")
-        result["metrics"] = per_layer(cell, red, traced, device["kind"])
+        result["metrics"] = per_layer(cell, layout, red, traced,
+                                      device["kind"])
         result["device"].update(busy_s=red.busy_s, window_s=red.window_s)
         result["breakdown"] = {"device_ops": red.top_ops(10),
                                "idle_gaps": red.idle_gaps(10)}

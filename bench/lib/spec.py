@@ -8,9 +8,14 @@ or cell lives in a file of its own, found by name:
     bench/metrics/<metric>.py           a per-layer metric's reader
     bench/limits/<workload>.json        the limit that decides `correct`
     bench/reference/<reference>.py      the plain reference a config names
+    bench/layouts/<reference>.py        how that architecture maps onto the
+                                        program: its config, weight rules,
+                                        the reference's weight views and
+                                        the work of its linears
 
 A new cell, configuration, mix or metric is new files and new entries; no
-existing file changes.
+existing file changes.  A configuration of a new architecture is its
+config, reference, layout and limits, and the cell's traffic if new.
 """
 from __future__ import annotations
 
@@ -89,3 +94,12 @@ def reference_module(root, config: Dict[str, Any]):
     name = config["reference"]
     return load_module(Path(root) / "bench" / "reference" / f"{name}.py",
                        f"bench_reference_{name}")
+
+
+def layout_module(root, config: Dict[str, Any]):
+    """The layout of the architecture `config` names by its `reference`:
+    the one benchmark file per architecture that knows the program's
+    config and parameter tree."""
+    name = config["reference"]
+    return load_module(Path(root) / "bench" / "layouts" / f"{name}.py",
+                       f"bench_layout_{name}")

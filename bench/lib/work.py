@@ -3,7 +3,8 @@
 Each step (`loop.StepRecord`) holds its prefill rows (tokens computed, KV
 length after) and, per decode iteration, the KV length each decode row
 attended.  Every attention layer calls the prefill kernel once for a step's
-prefill rows and the decode kernel once per decode iteration.
+prefill rows and the decode kernel once per decode iteration; the cell's
+layout says how many layers attend and what the linears' work is.
 """
 from __future__ import annotations
 
@@ -19,10 +20,11 @@ def _shape(cfg: Dict) -> Dict:
                 kv_bits=cfg["program"]["kv_bits"])
 
 
-def kernel_least_seconds(steps: Sequence, cfg: Dict, device_kind: str,
-                         kernel: str, page_size: int) -> float:
+def kernel_least_seconds(steps: Sequence, cfg: Dict, layout,
+                         device_kind: str, kernel: str,
+                         page_size: int) -> float:
     """Summed roofline least time of every call of `kernel` ("prefill" or
-    "decode") in `steps`, over all layers."""
+    "decode") in `steps`, over the layout's attention layers."""
     shape = dict(_shape(cfg), page_size=page_size)
     total = 0.0
     for s in steps:
@@ -33,10 +35,10 @@ def kernel_least_seconds(steps: Sequence, cfg: Dict, device_kind: str,
         for rows in calls:
             ops, nbytes = counts.attention_call(rows, **shape)
             total += counts.least_seconds(ops, nbytes, device_kind)
-    return total * cfg["num_hidden_layers"]
+    return total * layout.attention_layers(cfg)
 
 
-def model_ops(steps: Sequence, cfg: Dict) -> float:
+def model_ops(steps: Sequence, cfg: Dict, layout) -> float:
     """Model operations of every token the steps processed."""
     tokens, keys, logits = 0, 0.0, 0
     for s in steps:
@@ -47,7 +49,7 @@ def model_ops(steps: Sequence, cfg: Dict) -> float:
             tokens += len(it)
             keys += sum(it)
         logits += s.delivered
-    return counts.model_ops(cfg, tokens, logits, keys)
+    return counts.model_ops(cfg, layout, tokens, logits, keys)
 
 
 def mfu(ctx):
@@ -57,5 +59,5 @@ def mfu(ctx):
     are int8).  None without a trace or steps."""
     if ctx.reduction is None or not ctx.steps:
         return None
-    return (100.0 * model_ops(ctx.steps, ctx.config)
+    return (100.0 * model_ops(ctx.steps, ctx.config, ctx.layout)
             / ctx.reduction.window_s / ctx.peaks["int8_ops"])

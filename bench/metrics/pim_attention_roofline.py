@@ -10,8 +10,9 @@ def read(ctx):
     if ctx.reduction is None:
         return None
     spent = ctx.reduction.kernel_seconds(KERNEL)
-    least = work.kernel_least_seconds(ctx.steps, ctx.config, ctx.device_kind,
-                                      "prefill", ctx.server["page_size"])
+    least = work.kernel_least_seconds(ctx.steps, ctx.config, ctx.layout,
+                                      ctx.device_kind, "prefill",
+                                      ctx.server["page_size"])
     if spent <= 0 or least <= 0:
         return None
     return 100.0 * least / spent

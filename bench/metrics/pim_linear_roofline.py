@@ -1,11 +1,12 @@
 """The PIM linears of the layer stack (`core/pim.pim_linear_apply`, ops in
 the name scope `pim_linear/`): the roofline's least time of the traced
 steps' forwards over the linears' device time, in percent.  Least time per
-forward: max(2 * weights * tokens / int8 peak, weights * 1 B / HBM
-bandwidth), the int8 weights read once (`counts.matmul_params`).  A mixed
-step is one forward over its prefill tokens and decode rows; a decode
-chunk-scan is one forward per recorded iteration.  Layer: model step."""
-from bench.lib import counts, progspans
+forward: max(ops / int8 peak, bytes / HBM bandwidth), with the least
+(ops, bytes) of the cell's layout (`linear_work`: for a dense decoder 2 ops
+per weight per token, the int8 weights read once).  A mixed step is one
+forward over its prefill tokens and decode rows; a decode chunk-scan is one
+forward per recorded iteration.  Layer: model step."""
+from bench.lib import progspans
 
 
 def forwards(step):
@@ -17,18 +18,21 @@ def forwards(step):
     return [len(it) for it in step.decode]
 
 
-def least_seconds(steps, cfg, peaks) -> float:
-    w = counts.matmul_params(cfg)
-    return sum(max(2.0 * w * t / peaks["int8_ops"],
-                   w / peaks["hbm_bytes_per_s"])
-               for s in steps for t in forwards(s))
+def least_seconds(steps, cfg, layout, peaks) -> float:
+    total = 0.0
+    for s in steps:
+        for t in forwards(s):
+            ops, nbytes = layout.linear_work(cfg, t)
+            total += max(ops / peaks["int8_ops"],
+                         nbytes / peaks["hbm_bytes_per_s"])
+    return total
 
 
 def read(ctx):
     if ctx.reduction is None or not ctx.steps:
         return None
     spent = progspans.scoped_seconds(ctx.reduction, "pim_linear")
-    least = least_seconds(ctx.steps, ctx.config, ctx.peaks)
+    least = least_seconds(ctx.steps, ctx.config, ctx.layout, ctx.peaks)
     if spent <= 0 or least <= 0:
         return None
     return 100.0 * least / spent

@@ -45,19 +45,19 @@ def main(argv=None):
     from jax.sharding import SingleDeviceSharding
 
     from bench.lib import spec as S
-    from bench.lib.model import model_config
     from repro.models.model_zoo import build_model
     from repro.runtime import serve_lib
 
     jax.config.update("jax_enable_compilation_cache", False)
     cell = S.load_cell(ROOT, args.workload)
+    layout = S.layout_module(ROOT, cell.config)
     server = dict(cell.traffic["server"])
     if args.slots:
         server["max_batch_slots"] = args.slots
     if args.num_pages:
         server["num_pages"] = args.num_pages
     over = {"num_layers": args.layers} if args.layers else {}
-    cfg = model_config(cell.config, **over)
+    cfg = layout.program_config(cell.config, **over)
     model = build_model(cfg)
     B, max_len = server["max_batch_slots"], server["max_len"]
     ps, P = server["page_size"], server["num_pages"]
@@ -111,7 +111,7 @@ def main(argv=None):
               f"({total / 2**30:.2f} GiB)", flush=True)
     if args.chip:
         cell.traffic["server"] = server
-        harness.build_server(cell, 1, over)
+        harness.build_server(cell, layout, 1, over)
         stats = jax.devices()[0].memory_stats() or {}
         print("after every step program ran: " + ", ".join(
             f"{k} {v}" for k, v in sorted(stats.items())), flush=True)

@@ -50,7 +50,8 @@ def main(argv=None):
         cell.traffic["server"]["max_batch_slots"] = args.slots
     harness.device_check(cell.chips)
     harness.enable_compile_cache(ROOT)
-    cfg, _, sched, _ = harness.build_server(cell, args.seed, None)
+    cfg, _, sched, _ = harness.build_server(
+        cell, spec.layout_module(ROOT, cell.config), args.seed, None)
     server = cell.traffic["server"]
     for rate in (float(r) for r in args.rates.split(",")):
         mix = json.loads(json.dumps(cell.traffic))

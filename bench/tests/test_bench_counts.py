@@ -9,10 +9,11 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT)]
 
-from bench.lib import counts, work  # noqa: E402
+from bench.lib import counts, spec, work  # noqa: E402
 from bench.lib.loop import StepRecord  # noqa: E402
 
 CFG = json.loads((ROOT / "bench/configs/internlm2-1.8b.json").read_text())
+LAYOUT = spec.layout_module(ROOT, CFG)
 SHAPE = dict(heads=16, kv_heads=8, head_dim=128, page_size=16, kv_bits=8)
 
 
@@ -56,22 +57,24 @@ def test_decode_is_bandwidth_bound_long_chunk_compute_bound():
 
 
 def test_model_ops_internlm2():
-    assert counts.matmul_params(CFG) == 24 * (2048 * 128 * 48
+    assert LAYOUT.matmul_params(CFG) == 24 * (2048 * 128 * 48
                                               + 3 * 2048 * 8192)
     # one decode token at 2048 keys: weights, the head row, attention
     want = 2 * 1_509_949_440 + 2 * 2048 * 92544 + 4 * 16 * 128 * 24 * 2048
-    assert counts.model_ops(CFG, 1, 1, 2048) == want == 3_801_612_288
+    assert counts.model_ops(CFG, LAYOUT, 1, 1, 2048) == want == 3_801_612_288
 
 
 def test_step_work():
     s = StepRecord(0.0, 1.0, prefill=[(256, 512)],
                    decode=[[2048, 1000], [2049]], delivered=3)
     ops, nb = counts.attention_call([(256, 512)], **SHAPE)
-    assert work.kernel_least_seconds([s], CFG, "TPU v5 lite", "prefill",
-                                     16) == pytest.approx(24 * nb / 819e9)
+    assert work.kernel_least_seconds([s], CFG, LAYOUT, "TPU v5 lite",
+                                     "prefill", 16) == pytest.approx(
+                                         24 * nb / 819e9)
     dec = sum(counts.least_seconds(*counts.attention_call(
         [(1, L) for L in it], **SHAPE), "TPU v5 lite") for it in s.decode)
-    assert work.kernel_least_seconds([s], CFG, "TPU v5 lite", "decode",
-                                     16) == pytest.approx(24 * dec)
+    assert work.kernel_least_seconds([s], CFG, LAYOUT, "TPU v5 lite",
+                                     "decode", 16) == pytest.approx(24 * dec)
     keys = 98432 + 2048 + 1000 + 2049
-    assert work.model_ops([s], CFG) == counts.model_ops(CFG, 259, 3, keys)
+    assert work.model_ops([s], CFG, LAYOUT) == counts.model_ops(
+        CFG, LAYOUT, 259, 3, keys)

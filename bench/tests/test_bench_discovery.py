@@ -1,5 +1,6 @@
-"""BENCHMARK.json's shape, and a cell, configuration, mix or metric added
-as new files only: the harness finds each by its name."""
+"""BENCHMARK.json's shape, and a cell, configuration, mix, metric or
+architecture added as new files only: the harness finds each by its
+name."""
 import json
 import os
 import re
@@ -8,6 +9,7 @@ import subprocess
 import sys
 from pathlib import Path
 
+import numpy as np
 import pytest
 
 HERE = Path(__file__).resolve().parent
@@ -98,6 +100,91 @@ def test_new_config_mix_and_metric_are_found_by_name(tmp_path):
             assert p.read_bytes() == data, p
     with pytest.raises(KeyError):
         spec.load_cell(root, "tiny-wide.unknown")
+
+
+TINY_MOE = dict(
+    {k: v for k, v in tinyroot.CONFIG.items() if k != "intermediate_size"},
+    name="tiny-moe", reference="tiny_moe", num_hidden_layers=3,
+    num_key_value_heads=4, intermediate_size=64, moe_intermediate_size=64,
+    first_k_dense_replace=1, n_routed_experts=4, n_shared_experts=1,
+    num_experts_per_tok=2, norm_topk_prob=True)
+
+
+def test_new_architecture_is_new_files_only(tmp_path):
+    """A MoE architecture (a dense layer, then two layers of 4 routed
+    experts, top-2, beside a shared one) is its layout, its reference and
+    a config that names them; its `correct` check is not run here."""
+    import jax
+    from bench.lib.model import _leaf_name, make_params, seed_key
+    from repro.models.model_zoo import build_model
+
+    root = tinyroot.make(tmp_path / "root")
+    for sub in ("layouts", "reference"):      # private copies to add to
+        (root / "bench" / sub).unlink()
+        shutil.copytree(ROOT / "bench" / sub, root / "bench" / sub,
+                        ignore=shutil.ignore_patterns("__pycache__"))
+    before = {p: p.read_bytes() for p in root.rglob("*") if p.is_file()}
+
+    data = HERE / "data"
+    shutil.copy(data / "tiny_moe_layout.py",
+                root / "bench" / "layouts" / "tiny_moe.py")
+    shutil.copy(data / "tiny_moe_reference.py",
+                root / "bench" / "reference" / "tiny_moe.py")
+    (root / "bench" / "configs" / "tiny-moe.json").write_text(
+        json.dumps(TINY_MOE))
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["configs"].append({"name": "tiny-moe", "source": "test",
+                             "file": "bench/configs/tiny-moe.json",
+                             "reduced": [], "why": "test"})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    for p, data_ in before.items():
+        if p.name != "BENCHMARK.json":
+            assert p.read_bytes() == data_, p
+
+    entry = [c for c in spec.load_benchmark(root)["configs"]
+             if c["name"] == "tiny-moe"][0]
+    cfg = json.loads((root / entry["file"]).read_text())
+    layout = spec.layout_module(root, cfg)
+    assert Path(layout.__file__) == root / "bench/layouts/tiny_moe.py"
+    with pytest.raises(NotImplementedError):
+        spec.reference_module(root, cfg).make(cfg)
+    with pytest.raises(ValueError):
+        layout.program_config(dict(cfg, intermediate_size=128))
+
+    model = build_model(layout.program_config(cfg))
+    with pytest.raises(ValueError, match="no rule for weight leaf"):
+        make_params(model, 7)
+    params = make_params(model, 7, layout.WEIGHT_RULES)
+    flat = jax.tree_util.tree_flatten_with_path(params)[0]
+    names = [_leaf_name(p) for p, _ in flat]
+    for want in ("blocks/0/moe/router", "blocks/0/moe/experts/w_gate",
+                 "blocks/0/moe/experts/w_out", "blocks/0/moe/shared/w_in",
+                 "dense_prefix/0/mlp/w_gate/w", "dense_prefix/0/attn/wq/w"):
+        assert want in names, (want, names)
+    # the router is drawn by the layout's rule from its own fold of the
+    # seed, compiled as make_params compiles it
+    i = names.index("blocks/0/moe/router")
+    rule = jax.jit(lambda k: layout.WEIGHT_RULES["router"](k, (2, 128, 4)))
+    np.testing.assert_array_equal(flat[i][1],
+                                  rule(jax.random.fold_in(seed_key(7), i)))
+
+    views = layout.weight_views(params, cfg)
+    dense, moe = views["layer"](0), views["layer"](1)
+    assert "router" not in dense
+    np.testing.assert_array_equal(
+        dense["w_gate"], params["dense_prefix"][0]["mlp"]["w_gate"]["w"])
+    blocks = params["blocks"][0]["moe"]
+    np.testing.assert_array_equal(moe["router"], blocks["router"][0])
+    np.testing.assert_array_equal(views["layer"](2)["router"],
+                                  blocks["router"][1])
+    assert moe["experts"]["w_gate"].shape == (4, 128, 64)
+    assert moe["experts"]["w_out"].shape == (4, 64, 128)
+    assert moe["shared"]["w_in"].shape == (1, 128, 64)
+    # per token: 3 layers of attention (128 * 32 * 16 weights), the dense
+    # layer (3 * 128 * 64) and 2 MoE layers of 2 routed + 1 shared experts
+    per_token = 3 * 65536 + 24576 + 2 * 3 * 24576
+    assert layout.linear_work(cfg, 5) == (2 * per_token * 5, per_token)
+    assert layout.attention_layers(cfg) == 3
 
 
 def _run(cwd, env_extra=None):

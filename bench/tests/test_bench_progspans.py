@@ -50,9 +50,10 @@ SYNTHETIC = [
     (DEV, "XLA Ops", "copy_bitcast_fusion.4", 195, 2, REL),
 ]
 # hidden 64, d_ff 128, 4 q / 2 KV heads of 16, 2 layers: 73,728 weights
-CONFIG = {"hidden_size": 64, "intermediate_size": 128,
-          "num_attention_heads": 4, "num_key_value_heads": 2,
-          "head_dim": 16, "num_hidden_layers": 2}
+CONFIG = {"reference": "dense_decoder", "hidden_size": 64,
+          "intermediate_size": 128, "num_attention_heads": 4,
+          "num_key_value_heads": 2, "head_dim": 16, "num_hidden_layers": 2}
+LAYOUT = spec.layout_module(ROOT, CONFIG)
 PEAKS = {"int8_ops": 1e12, "hbm_bytes_per_s": 1e10}
 # a mixed step (a 100-token chunk beside two decode rows: one forward of
 # 102 tokens) and a decode chunk-scan of three recorded iterations
@@ -74,7 +75,7 @@ def _read(monkeypatch, rows, name):
         (e for e in evs if not e.plane.startswith("/device:")),
         key=lambda e: e.start))
     ctx = SimpleNamespace(reduction=red, steps=STEPS, config=CONFIG,
-                          peaks=PEAKS)
+                          layout=LAYOUT, peaks=PEAKS)
     return spec.metric_reader(ROOT, name)(ctx)
 
 
@@ -129,7 +130,7 @@ def test_none_without_spans_or_scopes(monkeypatch, name, trace):
             tracecut.Event(e.plane, e.line, e.name, e.start + 1e6, e.dur)
             for e in host])
         ctx = SimpleNamespace(reduction=tracecut.reduce(evs), steps=STEPS,
-                              config=CONFIG, peaks=PEAKS)
+                              config=CONFIG, layout=LAYOUT, peaks=PEAKS)
         got = spec.metric_reader(ROOT, name)(ctx)
         none = spans
     else:
@@ -145,9 +146,9 @@ def test_helper_finds_a_traced_runs_spans(tmp_path, monkeypatch):
     seen = {}
     real = harness.per_layer
 
-    def spy(cell, red, steps, device_kind):
+    def spy(cell, layout, red, steps, device_kind):
         seen.update(red=red, steps=steps)
-        return real(cell, red, steps, device_kind)
+        return real(cell, layout, red, steps, device_kind)
     monkeypatch.setattr(harness, "per_layer", spy)
     # the CPU has no peaks; the readers need some to run
     monkeypatch.setitem(counts.PEAKS, "cpu", counts.PEAKS["TPU v5e"])

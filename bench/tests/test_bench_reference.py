@@ -17,22 +17,20 @@ def test_reference_matches_float_program():
     import jax
     import jax.numpy as jnp
     from bench.lib import check, spec
-    from bench.lib.model import layer_weights, make_params, model_config
+    from bench.lib.model import make_params
     from repro.models.model_zoo import build_model
 
-    cfg = model_config(tinyroot.CONFIG, pim_linears=False,
-                       compute_dtype="float32")
+    layout = spec.layout_module(ROOT, tinyroot.CONFIG)
+    cfg = layout.program_config(tinyroot.CONFIG, pim_linears=False,
+                                compute_dtype="float32")
     model = build_model(cfg)
-    params = make_params(model, 2**32 + 3)
+    params = make_params(model, 2**32 + 3, layout.WEIGHT_RULES)
     toks = np.random.default_rng(0).integers(0, cfg.vocab_size, 40)
     with jax.default_matmul_precision("highest"):
         want = np.asarray(jax.jit(model.forward_train)(
             params, {"tokens": jnp.asarray(toks)[None]})[0][0])
     ref = spec.reference_module(ROOT, tinyroot.CONFIG).make(tinyroot.CONFIG)
-    weights = {"embed": params["embed"]["table"],
-               "head": params["unembed"]["table"],
-               "final_norm": params["final_norm"]["scale"],
-               "layer": lambda i: layer_weights(params, i)}
+    weights = layout.weight_views(params, tinyroot.CONFIG)
     got = ref(weights, toks.tolist(), list(range(40)))
     err = np.linalg.norm(got - want) / np.linalg.norm(want)
     assert err < 1e-4, err

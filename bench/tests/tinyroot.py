@@ -1,6 +1,6 @@
 """A benchmark root at a size the CPU runs: one tiny configuration of the
-same dense decoder and two small mixes, beside the real metric readers and
-reference.  Tests run the whole harness on it."""
+same dense decoder and two small mixes, beside the real metric readers,
+references and layouts.  Tests run the whole harness on it."""
 from __future__ import annotations
 
 import json
@@ -47,7 +47,7 @@ def make(root: Path) -> Path:
     (root / "bench" / "configs").mkdir(parents=True)
     (root / "bench" / "traffic").mkdir()
     (root / "bench" / "limits").mkdir()
-    for sub in ("metrics", "reference"):
+    for sub in ("metrics", "reference", "layouts"):
         (root / "bench" / sub).symlink_to(BENCH / sub)
     (root / "bench" / "configs" / "tiny.json").write_text(json.dumps(CONFIG))
     cells = []
