@@ -76,11 +76,27 @@ class LUTSoftmaxConfig:
 # ---------------------------------------------------------------------------
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    num_experts: int = 0           # routed experts (0 = dense FFN)
+    num_experts: int = 0           # routed experts = router outputs (0 = dense FFN)
     num_shared: int = 0            # always-on shared experts (DeepSeekMoE)
     top_k: int = 2
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25  # training's capacity routing only
     router_aux_weight: float = 0.01
+    # renormalise the top-k gates to sum to 1 (DeepSeekMoE's
+    # `norm_topk_prob`); False keeps the softmax probabilities as gates
+    norm_topk_prob: bool = True
+    # the routed experts this layer holds, as one chip's share of an
+    # expert-parallel deployment: experts [first_held, first_held +
+    # num_held) of the router's `num_experts` (num_held 0: all of them)
+    num_held: int = 0
+    first_held: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.num_held or self.num_experts
+
+    @property
+    def holds_share(self) -> bool:
+        return self.held < self.num_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -92,7 +108,8 @@ class ModelConfig:
     num_heads: int = 4
     num_kv_heads: int = 4
     head_dim: int = 0              # 0 -> d_model // num_heads
-    d_ff: int = 1024
+    d_ff: int = 1024               # FFN width (MoE: each expert's)
+    dense_d_ff: int = 0            # MoE dense prefix's FFN width (0 -> d_ff)
     vocab_size: int = 1024
     activation: str = "swiglu"     # swiglu|geglu|gelu|relu_sq
     norm: str = "rmsnorm"          # rmsnorm|layernorm
@@ -145,27 +162,40 @@ class ModelConfig:
         return self.head_dim if self.head_dim else self.d_model // self.num_heads
 
     @property
+    def resolved_dense_d_ff(self) -> int:
+        return self.dense_d_ff or self.d_ff
+
+    @property
     def q_per_kv(self) -> int:
         return self.num_heads // max(self.num_kv_heads, 1)
 
+    def _layer_kinds(self) -> Tuple[str, ...]:
+        """Each layer's kind: a MoE stack's dense prefix ("dense"), then
+        the pattern repeated (`transformer.pattern_layout`)."""
+        pat = self.block_pattern
+        n = self.num_dense_layers if "moe" in pat else 0
+        rest = self.num_layers - n
+        return ("dense",) * n + (pat * -(-rest // len(pat)))[:rest]
+
+    def _ffn_params(self, width: int) -> int:
+        glu = self.activation in ("swiglu", "geglu")
+        return (3 if glu else 2) * self.d_model * width
+
     def param_count(self) -> int:
-        """Analytic parameter count (embedding + blocks + head)."""
+        """Analytic parameter count (embedding + blocks + head).  A MoE
+        layer counts its held experts (`MoEConfig.held`) and its router."""
         d, h = self.d_model, self.resolved_head_dim
         n_q, n_kv = self.num_heads, self.num_kv_heads
         attn = d * h * n_q + 2 * d * h * n_kv + h * n_q * d
-        if self.activation in ("swiglu", "geglu"):
-            ffn_dense = 3 * d * self.d_ff
-        else:
-            ffn_dense = 2 * d * self.d_ff
-        if self.moe.num_experts:
-            ffn = (self.moe.num_experts + self.moe.num_shared) * ffn_dense
-            ffn += d * self.moe.num_experts  # router
-        else:
-            ffn = ffn_dense
-        kinds = _pattern_kinds(self)
+        ffn_dense = self._ffn_params(self.d_ff)
         per_layer = []
-        for kind in kinds:
-            if kind == "attn":
+        for kind in self._layer_kinds():
+            if kind == "dense":
+                ffn = self._ffn_params(self.resolved_dense_d_ff)
+                per_layer.append(attn + ffn + 2 * d)
+            elif kind == "moe":
+                ffn = (self.moe.held + self.moe.num_shared) * ffn_dense
+                ffn += d * self.moe.num_experts  # router
                 per_layer.append(attn + ffn + 2 * d)
             elif kind == "rglru":
                 w = self.lru_width or d
@@ -175,7 +205,7 @@ class ModelConfig:
                 # xlstm-style block: qkv+gates+out ~ 4*d*d + 2*d*4*d up/down
                 per_layer.append(4 * d * d + 2 * d * 4 * d + 2 * d)
             else:
-                per_layer.append(attn + ffn + 2 * d)
+                per_layer.append(attn + ffn_dense + 2 * d)
         total = sum(per_layer)
         total += self.vocab_size * d  # embedding
         if not self.tie_embeddings:
@@ -190,12 +220,11 @@ class ModelConfig:
         """Params active per token (MoE: only top_k + shared experts)."""
         if not self.moe.num_experts:
             return self.param_count()
-        d = self.d_model
-        ffn_dense = (3 if self.activation in ("swiglu", "geglu") else 2) * d * self.d_ff
-        dense_total = self.param_count()
-        all_experts = self.num_layers * (self.moe.num_experts + self.moe.num_shared) * ffn_dense
-        active = self.num_layers * (self.moe.top_k + self.moe.num_shared) * ffn_dense
-        return dense_total - all_experts + active
+        m = self.moe
+        n_moe = self._layer_kinds().count("moe")
+        ffn = self._ffn_params(self.d_ff)
+        idle = n_moe * (m.held - min(m.top_k, m.held)) * ffn
+        return self.param_count() - idle
 
 
 def _pattern_kinds(cfg: ModelConfig) -> Tuple[str, ...]:

@@ -23,7 +23,7 @@ straight-through gradients; see DESIGN.md §2).
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -32,7 +32,7 @@ from repro.configs.base import ModelConfig
 from repro.core import attention as A
 from repro.core import pim
 from repro.models import layers as L
-from repro.models.moe import moe_ffn_apply, moe_ffn_init
+from repro.models.moe import moe_ffn_apply, moe_ffn_init, moe_ffn_serve
 
 
 # ===========================================================================
@@ -70,7 +70,9 @@ def _qkv(params, x, cfg: ModelConfig, pos_ids):
 
 
 def attn_block_init(key, cfg: ModelConfig, window: int = 0, moe: bool = False,
-                    cross: bool = False, causal: bool = True):
+                    cross: bool = False, causal: bool = True,
+                    d_ff: Optional[int] = None):
+    """`d_ff` sets the dense FFN's width (default `cfg.d_ff`)."""
     keys = jax.random.split(key, 5)
     p = {
         "norm1": L.norm_init(cfg.d_model, cfg.norm),
@@ -80,7 +82,7 @@ def attn_block_init(key, cfg: ModelConfig, window: int = 0, moe: bool = False,
     if moe:
         p["moe"] = moe_ffn_init(keys[1], cfg)
     else:
-        p["mlp"] = L.mlp_init(keys[1], cfg)
+        p["mlp"] = L.mlp_init(keys[1], cfg, d_ff=d_ff)
     if cross:
         p["norm_x"] = L.norm_init(cfg.d_model, cfg.norm)
         p["xattn"] = _attn_init(keys[2], cfg)
@@ -253,6 +255,10 @@ def attn_block_fwd_serve(params, x, cache: A.KVCache, offset, cfg: ModelConfig,
     (current token + drafted continuations), all verified through the
     split-K decode launch in one step.  The behavioral path again needs
     no routing (ragged per-row positions already cover it).
+
+    Returns (x, cache); a MoE block returns (x, cache, load), the load
+    counts of its dropless FFN over this step's valid tokens
+    (`moe.moe_ffn_serve`).
     """
     B, S, _ = x.shape
     ragged = getattr(offset, "ndim", 0) >= 1
@@ -312,8 +318,12 @@ def attn_block_fwd_serve(params, x, cache: A.KVCache, offset, cfg: ModelConfig,
     )
     x = x + o
     h = L.norm_apply(params["norm2"], x, cfg.norm)
-    y, _ = _ffn(params, h, cfg)
-    return x + y, cache
+    if "moe" in params:
+        valid = (None if seq_lens is None else
+                 jnp.arange(S)[None, :] < jnp.asarray(seq_lens)[:, None])
+        y, load = moe_ffn_serve(params["moe"], h, cfg, valid)
+        return x + y, cache, load
+    return x + L.mlp_apply(params["mlp"], h, cfg), cache
 
 
 # ===========================================================================

@@ -121,11 +121,11 @@ def build_model(cfg: ModelConfig) -> Model:
 
     def forward_serve(params, batch, cache, offset, enc_out=None,
                       seq_lens=None, pages=None, decode_rows=None,
-                      logit_positions=None, verify_len=1):
+                      logit_positions=None, verify_len=1, moe_load=False):
         return T.forward_serve(params, batch, cache, offset, cfg,
                                enc_out=enc_out, seq_lens=seq_lens,
                                pages=pages, decode_rows=decode_rows,
                                logit_positions=logit_positions,
-                               verify_len=verify_len)
+                               verify_len=verify_len, moe_load=moe_load)
 
     return Model(cfg, init, forward_train, loss, init_cache, forward_serve)

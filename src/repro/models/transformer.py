@@ -134,13 +134,6 @@ def pattern_layout(cfg: ModelConfig) -> Tuple[Tuple[str, ...], int, Tuple[str, .
     return pat, R, tail
 
 
-def _moe_kind_for_layer(cfg: ModelConfig, kind: str, layer_idx: int) -> str:
-    """deepseek-moe keeps the first `num_dense_layers` layers dense."""
-    if kind == "moe" and layer_idx < cfg.num_dense_layers:
-        return "attn"
-    return kind
-
-
 # ---------------------------------------------------------------------------
 # init
 # ---------------------------------------------------------------------------
@@ -164,23 +157,20 @@ def init_params(key, cfg: ModelConfig) -> Dict[str, Any]:
     stacks = []
     for j, kind in enumerate(pat):
         kj = jax.random.fold_in(keys[4], j)
-        # layer index of repetition r at position j is r*len(pat)+j; MoE
-        # dense-prefix handling only matters when the prefix is in the stack,
-        # so those layers live in a dense stack variant only if pattern is
-        # uniform "moe" — handled by giving repetition 0 its own tail below.
         stack = jax.vmap(lambda k: _block_init(kind, k, cfg))(
             jax.random.split(kj, R))
         stacks.append(stack)
     params["blocks"] = tuple(stacks)
     params["tail"] = tuple(
-        _block_init(_moe_kind_for_layer(cfg, kind, R * len(pat) + i),
-                    jax.random.fold_in(keys[5], i), cfg)
+        _block_init(kind, jax.random.fold_in(keys[5], i), cfg)
         for i, kind in enumerate(tail)
     )
-    # dense-prefix override for MoE archs (deepseek): separate dense params
+    # dense prefix of MoE archs (deepseek): its own dense blocks, at the
+    # dense FFN width, ahead of the stacks and the tail
     if cfg.num_dense_layers and "moe" in pat:
         params["dense_prefix"] = tuple(
-            _block_init("attn", jax.random.fold_in(keys[6], i), cfg)
+            B.attn_block_init(jax.random.fold_in(keys[6], i), cfg,
+                              d_ff=cfg.resolved_dense_d_ff)
             for i in range(cfg.num_dense_layers)
         )
     params["final_norm"] = L.norm_init(cfg.d_model, cfg.norm)
@@ -294,9 +284,8 @@ def forward_hidden(params, batch: Dict[str, jax.Array], cfg: ModelConfig):
     (x, aux), _ = jax.lax.scan(scan_body, (x, jnp.float32(0.0)),
                                params["blocks"])
     for i, kind in enumerate(tail):
-        x, a = _block_fwd_train(
-            _moe_kind_for_layer(cfg, kind, R * len(pat) + i),
-            params["tail"][i], x, pos_ids, cfg, enc_out=enc_out)
+        x, a = _block_fwd_train(kind, params["tail"][i], x, pos_ids, cfg,
+                                enc_out=enc_out)
         aux += a
     x = L.norm_apply(params["final_norm"], x, cfg.norm)
     return x, aux
@@ -475,7 +464,7 @@ def forward_serve(params, batch: Dict[str, jax.Array], cache, offset,
                   pages: Optional[jax.Array] = None,
                   decode_rows: Optional[jax.Array] = None,
                   logit_positions: Optional[jax.Array] = None,
-                  verify_len: int = 1):
+                  verify_len: int = 1, moe_load: bool = False):
     """One serve step (prefill chunk, single-token decode, or a MIXED batch
     of both).
 
@@ -509,7 +498,11 @@ def forward_serve(params, batch: Dict[str, jax.Array], cache, offset,
 
     Returns (logits (B,V), new_cache, enc_out) — logits are (B, P, V) when
     `logit_positions` is given; enc_out is computed on the first
-    (offset==0) call for encoder-decoder archs and threaded back.
+    (offset==0) call for encoder-decoder archs and threaded back.  With
+    `moe_load` a fourth output follows: int32 (2,), the MoE blocks' load
+    counts summed over layers (routed assignments of valid tokens that
+    reached held experts, and (layer, held expert) pairs that received
+    any; zeros for a stack without MoE blocks).
     """
     pat, R, tail = pattern_layout(cfg)
     x = _embed_inputs(params, batch, cfg, offset=offset)
@@ -517,40 +510,52 @@ def forward_serve(params, batch: Dict[str, jax.Array], cache, offset,
         enc_out = encode(params, batch["frames"], cfg)
 
     new_cache = dict(cache)
+    loads = []                      # MoE blocks' load counts
+
+    def block(kind, p, x, st):
+        out = _block_fwd_serve(kind, p, x, st, offset, cfg, enc_out=enc_out,
+                               seq_lens=seq_lens, pages=pages,
+                               decode_rows=decode_rows,
+                               verify_len=verify_len)
+        return out if kind == "moe" else out + (None,)
+
     if "dense_prefix" in cache:
         dp = []
         for p, st in zip(params["dense_prefix"], cache["dense_prefix"]):
-            x, st = _block_fwd_serve("attn", p, x, st, offset, cfg,
-                                     seq_lens=seq_lens, pages=pages,
-                                     decode_rows=decode_rows,
-                                     verify_len=verify_len)
+            x, st, _ = block("attn", p, x, st)
             dp.append(st)
         new_cache["dense_prefix"] = tuple(dp)
 
     def scan_body(x, xs):
         group_params, group_state = xs
-        new_states = []
+        new_states, group_loads = [], []
         for j, kind in enumerate(pat):
-            x, st = _block_fwd_serve(kind, group_params[j], x, group_state[j],
-                                     offset, cfg, enc_out=enc_out,
-                                     seq_lens=seq_lens, pages=pages,
-                                     decode_rows=decode_rows,
-                                     verify_len=verify_len)
+            x, st, load = block(kind, group_params[j], x, group_state[j])
             new_states.append(st)
+            if load is not None:
+                group_loads.append(load)
+        if group_loads:
+            return x, (tuple(new_states), sum(group_loads))
         return x, tuple(new_states)
 
-    x, new_block_states = jax.lax.scan(
-        scan_body, x, (params["blocks"], cache["blocks"]))
-    new_cache["blocks"] = new_block_states
+    x, ys = jax.lax.scan(scan_body, x, (params["blocks"], cache["blocks"]))
+    if "moe" in pat:
+        ys, stack_loads = ys
+        loads.append(jnp.sum(stack_loads, axis=0))
+    new_cache["blocks"] = ys
     new_tail = []
     for i, kind in enumerate(tail):
-        x, st = _block_fwd_serve(
-            _moe_kind_for_layer(cfg, kind, R * len(pat) + i),
-            params["tail"][i], x, cache["tail"][i], offset, cfg,
-            enc_out=enc_out, seq_lens=seq_lens, pages=pages,
-            decode_rows=decode_rows, verify_len=verify_len)
+        x, st, load = block(kind, params["tail"][i], x, cache["tail"][i])
         new_tail.append(st)
+        if load is not None:
+            loads.append(load)
     new_cache["tail"] = tuple(new_tail)
+
+    def done(*out):
+        if not moe_load:
+            return out
+        return out + (sum(loads) if loads else jnp.zeros((2,), jnp.int32),)
+
     if logit_positions is not None:
         # verify mode: logits at EVERY requested column — (B, P, V).
         # Per-position hidden states are position-wise, so column j equals
@@ -560,7 +565,7 @@ def forward_serve(params, batch: Dict[str, jax.Array], cache, offset,
         x = jnp.take_along_axis(x, idx[:, :, None], axis=1)
         x = L.norm_apply(params["final_norm"], x, cfg.norm)
         head = params["embed"] if cfg.tie_embeddings else params["unembed"]
-        return L.unembed_apply(head, x), new_cache, enc_out
+        return done(L.unembed_apply(head, x), new_cache, enc_out)
     if seq_lens is not None:
         # per-row last valid position (rows with seq_len == 0 read index 0;
         # their logits are garbage and the caller masks them out)
@@ -571,4 +576,4 @@ def forward_serve(params, batch: Dict[str, jax.Array], cache, offset,
     x = L.norm_apply(params["final_norm"], x, cfg.norm)
     head = params["embed"] if cfg.tie_embeddings else params["unembed"]
     logits = L.unembed_apply(head, x)[:, 0]
-    return logits, new_cache, enc_out
+    return done(logits, new_cache, enc_out)

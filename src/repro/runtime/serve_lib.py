@@ -241,6 +241,13 @@ def sample_logits_per_row(logits: jax.Array, keys, temperature: float = 0.0,
     )(logits, keys)
 
 
+def _moe_stack(model: Model) -> bool:
+    """MoE stacks' step programs return one more output, last: the step's
+    MoE load counts, int32 (2,), summed over its forwards and layers (see
+    `transformer.forward_serve(moe_load=True)`)."""
+    return "moe" in model.cfg.block_pattern
+
+
 def scheduler_supported(cfg: ModelConfig) -> bool:
     """The slot scheduler serves pure attention stacks: recurrent/ring states
     can't be length-masked per slot (their state mixes padded positions in),
@@ -264,16 +271,19 @@ def make_ragged_prefill_fn(model: Model, n: int, pad_len: int, max_len: int,
     finite; a poisoned (NaN/Inf) row samples -1 and is quarantined by the
     host (`status="poisoned"`) instead of emitting garbage.
     """
+    moe = _moe_stack(model)
+
     def prefill(params, tokens, lens, big_cache, slots, rids, gens, base_key):
         sub = model.init_cache(n, max_len, ragged=True)
         offs = jnp.zeros((n,), jnp.int32)
-        logits, sub, _ = model.forward_serve(
-            params, {"tokens": tokens}, sub, offs, seq_lens=lens)
+        logits, sub, _, *load = model.forward_serve(
+            params, {"tokens": tokens}, sub, offs, seq_lens=lens,
+            moe_load=moe)
         fin = jnp.all(jnp.isfinite(logits), axis=-1)
         tok0 = sample_logits_per_row(logits, _row_keys(base_key, rids, gens),
                                      temperature, top_k, top_p)
         tok0 = jnp.where(fin, tok0, -1)
-        return T.cache_scatter(big_cache, sub, slots), tok0, fin
+        return (T.cache_scatter(big_cache, sub, slots), tok0, fin, *load)
 
     return jax.jit(prefill, donate_argnums=(3,))
 
@@ -296,16 +306,19 @@ def make_paged_prefill_fn(model: Model, n: int, pad_len: int,
     as a full prefill would (same quantized bytes -> bit-identical
     logits).
     """
+    moe = _moe_stack(model)
+
     def prefill(params, tokens, lens, big_cache, pages, offs, rids, gens,
                 base_key):
-        logits, big_cache, _ = model.forward_serve(
+        logits, big_cache, _, *load = model.forward_serve(
             params, {"tokens": tokens}, big_cache,
-            jnp.asarray(offs, jnp.int32), seq_lens=lens, pages=pages)
+            jnp.asarray(offs, jnp.int32), seq_lens=lens, pages=pages,
+            moe_load=moe)
         fin = jnp.all(jnp.isfinite(logits), axis=-1)
         tok0 = sample_logits_per_row(logits, _row_keys(base_key, rids, gens),
                                      temperature, top_k, top_p)
         tok0 = jnp.where(fin, tok0, -1)
-        return big_cache, tok0, fin
+        return (big_cache, tok0, fin, *load)
 
     return jax.jit(prefill, donate_argnums=(3,))
 
@@ -379,18 +392,20 @@ def make_ragged_decode_fn(model: Model, chunk: int, temperature: float,
 
     Returns decode(params, tok, cache, lengths, active, remaining, rids,
     gens, base_key, poison[, pages]) -> (tok, cache, lengths, active,
-    remaining, toks (chunk, B), emitted (chunk, B) bool, pois (B,) bool).
+    remaining, toks (chunk, B), emitted (chunk, B) bool, pois (B,) bool),
+    and a MoE stack's load counts last (`_moe_stack`).
     """
     eos = -2 if eos_id is None else int(eos_id)   # -2 never matches a token
+    moe = _moe_stack(model)
 
     def decode(params, tok, cache, lengths, active, remaining, rids, gens,
                base_key, poison, pages=None):
         def body(carry, t):
-            tok, cache, lengths, active, remaining, gens, pois = carry
+            tok, cache, lengths, active, remaining, gens, pois, *load = carry
             act = active.astype(jnp.int32)
-            logits, cache, _ = model.forward_serve(
+            logits, cache, _, *step_load = model.forward_serve(
                 params, {"tokens": tok[:, None]}, cache, lengths,
-                seq_lens=act, pages=pages)
+                seq_lens=act, pages=pages, moe_load=moe)
             logits = jnp.where((poison & (t == 0))[:, None], jnp.nan, logits)
             fin = jnp.all(jnp.isfinite(logits), axis=-1)
             nxt = sample_logits_per_row(logits,
@@ -404,14 +419,16 @@ def make_ragged_decode_fn(model: Model, chunk: int, temperature: float,
             # decode kernel's per-slot early-out then runs zero partitions
             lengths = jnp.where(active & ~new_active, 0, new_len)
             carry = (nxt, cache, lengths, new_active, remaining - act,
-                     gens + act, pois | (active & ~fin))
+                     gens + act, pois | (active & ~fin),
+                     *(a + b for a, b in zip(load, step_load)))
             return carry, (nxt, active & fin)
 
+        load0 = (jnp.zeros((2,), jnp.int32),) if moe else ()
         carry, (toks, emitted) = jax.lax.scan(
             body, (tok, cache, lengths, active, remaining, gens,
-                   jnp.zeros_like(active)),
+                   jnp.zeros_like(active), *load0),
             jnp.arange(chunk))
-        return carry[:5] + (toks, emitted, carry[6])
+        return carry[:5] + (toks, emitted) + carry[6:]
 
     return jax.jit(decode, donate_argnums=(2,))
 
@@ -437,18 +454,22 @@ def make_mixed_step_fn(model: Model, n: int, pad_len: int,
     gens, base_key, poison[, pages]) -> (cache, tok (n,), fin (n,) bool);
     `poison` NaN-injects the named rows' logits and `fin` reports which
     rows stayed finite — the host quarantines ~fin rows (`"poisoned"`).
+    A MoE stack's load counts follow last (`_moe_stack`).
     """
+    moe = _moe_stack(model)
+
     def step(params, toks, cache, offs, seq_lens, decode_rows, rids, gens,
              base_key, poison, pages=None):
-        logits, cache, _ = model.forward_serve(
+        logits, cache, _, *load = model.forward_serve(
             params, {"tokens": toks}, cache, jnp.asarray(offs, jnp.int32),
-            seq_lens=seq_lens, pages=pages, decode_rows=decode_rows)
+            seq_lens=seq_lens, pages=pages, decode_rows=decode_rows,
+            moe_load=moe)
         logits = jnp.where(poison[:, None], jnp.nan, logits)
         fin = jnp.all(jnp.isfinite(logits), axis=-1)
         tok = sample_logits_per_row(logits, _row_keys(base_key, rids, gens),
                                     temperature, top_k, top_p)
         tok = jnp.where(fin, tok, -1)
-        return cache, tok, fin
+        return (cache, tok, fin, *load)
 
     return jax.jit(step, donate_argnums=(2,))
 
@@ -556,9 +577,11 @@ def make_spec_step_fn(model: Model, n: int, pad_len: int, verify_len: int,
     gens, base_key, poison[, pages]) -> (cache, out (n, verify_len),
     n_emit (n,), fin (n,) bool) where row b's emitted tokens are
     out[b, :n_emit[b]]; `poison` NaN-injects the named rows' logits and
-    the host discards every token of a ~fin row (quarantine).
+    the host discards every token of a ~fin row (quarantine).  A MoE
+    stack's load counts follow last (`_moe_stack`).
     """
     P = int(verify_len)
+    moe = _moe_stack(model)
 
     def step(params, toks, cache, offs, seq_lens, decode_rows, rids, gens,
              base_key, poison, pages=None):
@@ -568,10 +591,10 @@ def make_spec_step_fn(model: Model, n: int, pad_len: int, verify_len: int,
         pos = jnp.where(decode_rows[:, None],
                         jnp.minimum(col[None, :], last[:, None]),
                         jnp.broadcast_to(last[:, None], (n, P)))
-        logits, cache, _ = model.forward_serve(
+        logits, cache, _, *load = model.forward_serve(
             params, {"tokens": toks}, cache, jnp.asarray(offs, jnp.int32),
             seq_lens=sl, pages=pages, decode_rows=decode_rows,
-            logit_positions=pos, verify_len=P)          # (n, P, V)
+            logit_positions=pos, verify_len=P, moe_load=moe)  # (n, P, V)
         logits = jnp.where(poison[:, None, None], jnp.nan, logits)
         fin = jnp.all(jnp.isfinite(logits), axis=(1, 2))
         drafts = toks[:, 1:P]                           # (n, P-1)
@@ -581,7 +604,7 @@ def make_spec_step_fn(model: Model, n: int, pad_len: int, verify_len: int,
             match = (drafts == out[:, : P - 1]) & valid
             acc = jnp.sum(jnp.cumprod(match.astype(jnp.int32), axis=-1),
                           axis=-1)
-            return cache, out, acc + 1, fin
+            return (cache, out, acc + 1, fin, *load)
         keys = _row_key_grid(base_key, rids, gens, P)   # (n, P) keys
         lt = _truncate_logits(logits.astype(jnp.float32) / temperature,
                               top_k, top_p)             # (n, P, V)
@@ -611,7 +634,7 @@ def make_spec_step_fn(model: Model, n: int, pad_len: int, verify_len: int,
         shifted = jnp.concatenate(
             [drafts, jnp.zeros((n, 1), toks.dtype)], axis=1)  # (n, P)
         out = jnp.where(col[None, :] < acc[:, None], shifted, t[:, None])
-        return cache, out.astype(jnp.int32), acc + 1, fin
+        return (cache, out.astype(jnp.int32), acc + 1, fin, *load)
 
     return jax.jit(step, donate_argnums=(2,))
 
@@ -649,7 +672,9 @@ prefix-directory entries (distinct from None == pool full)."""
 # `sched.dispatch` (argument transfers and the jitted call; arguments name
 # the program and its shape), `sched.readback` (reads of its outputs) and
 # `sched.commit` (token bookkeeping and retirement), and a last
-# `sched.commit` for the step's closing hooks.
+# `sched.commit` for the step's closing hooks.  A MoE stack's step also
+# marks `moe.load` after its readback, with the load counts its program
+# returned as arguments (`Scheduler._mark_load`).
 _span = jax.profiler.TraceAnnotation
 
 
@@ -913,6 +938,7 @@ class Scheduler:
                 "encoder-decoder)")
         self.model = model
         self.params = params
+        self._moe = _moe_stack(model)
         self.B = int(max_batch_slots)
         self.max_len = int(max_len)
         self.eos_id = eos_id
@@ -1617,6 +1643,24 @@ class Scheduler:
                 if self.page_ref[p] == 0:
                     self.free_pages.append(p)
 
+    def _split_load(self, out):
+        """A step program's outputs, and its MoE load counts (the last
+        output of a MoE stack's program; None otherwise)."""
+        if self._moe:
+            return out[:-1], out[-1]
+        return out, None
+
+    @staticmethod
+    def _mark_load(load):
+        """The `moe.load` span: the step's routed assignments that reached
+        held experts and its (forward, layer, held expert) triples that
+        received any, read back with its tokens."""
+        if load is not None:
+            load = np.asarray(load)
+            with _span("moe.load", assignments=int(load[0]),
+                       active=int(load[1])):
+                pass
+
     def _retire(self, slot: int, status: str = "done",
                 register: bool = True):
         """Vacate `slot`.  `status` lands on the request (`"done"` for a
@@ -1807,11 +1851,11 @@ class Scheduler:
                                          self.pages_in_use())
             fn = make_paged_prefill_fn(self.model, n, L, self.temperature,
                                        self.top_k, self.top_p)
-            self.cache, tok0, fin = fn(self.params, jnp.asarray(toks),
-                                       jnp.asarray(lens), self.cache,
-                                       jnp.asarray(self.page_table[slots]),
-                                       jnp.asarray(offs_a), jnp.asarray(rids),
-                                       jnp.asarray(gens), self.key)
+            (self.cache, tok0, fin), load = self._split_load(fn(
+                self.params, jnp.asarray(toks), jnp.asarray(lens),
+                self.cache, jnp.asarray(self.page_table[slots]),
+                jnp.asarray(offs_a), jnp.asarray(rids), jnp.asarray(gens),
+                self.key))
             fin_a = np.asarray(fin)
             if self.prefix_sharing:
                 # the wave's prompt KV is now fully valid: publish every
@@ -1826,12 +1870,13 @@ class Scheduler:
             fn = make_ragged_prefill_fn(self.model, n, L, self.max_len,
                                         self.temperature, self.top_k,
                                         self.top_p)
-            self.cache, tok0, fin = fn(self.params, jnp.asarray(toks),
-                                       jnp.asarray(lens), self.cache,
-                                       jnp.asarray(slots), jnp.asarray(rids),
-                                       jnp.asarray(gens), self.key)
+            (self.cache, tok0, fin), load = self._split_load(fn(
+                self.params, jnp.asarray(toks), jnp.asarray(lens),
+                self.cache, jnp.asarray(slots), jnp.asarray(rids),
+                jnp.asarray(gens), self.key))
             fin_a = np.asarray(fin)
         tok0 = np.asarray(tok0)
+        self._mark_load(load)
         for i, (s, r) in enumerate(wave):
             self.slot_req[s] = r
             self._admit_counter += 1
@@ -1942,6 +1987,7 @@ class Scheduler:
                 out = fn(*args, jnp.asarray(self.page_table))
             else:
                 out = fn(*args)
+            out, load = self._split_load(out)
             tok, self.cache, lengths, active, remaining, toks, em, pois = out
         with _span("sched.readback"):
             tok = np.array(tok)
@@ -1951,6 +1997,8 @@ class Scheduler:
             toks = np.asarray(toks)                    # (chunk, B)
             em = np.asarray(em)
             pois = np.asarray(pois)
+            load = None if load is None else np.asarray(load)
+        self._mark_load(load)
         with _span("sched.commit"):
             stalled = self.active & ~run
             self.cur_tok = np.where(run, tok, self.cur_tok)
@@ -2076,14 +2124,15 @@ class Scheduler:
                                        self.top_k, self.top_p)
             rows = self.page_table[slots]
         with _span("sched.dispatch", program="prefill_wave", n=n, L=L):
-            self.cache, tok0, fin = fn(self.params, jnp.asarray(toks),
-                                       jnp.asarray(lens), self.cache,
-                                       jnp.asarray(rows), jnp.asarray(offs),
-                                       jnp.asarray(rids), jnp.asarray(gens),
-                                       self.key)
+            (self.cache, tok0, fin), load = self._split_load(fn(
+                self.params, jnp.asarray(toks), jnp.asarray(lens),
+                self.cache, jnp.asarray(rows), jnp.asarray(offs),
+                jnp.asarray(rids), jnp.asarray(gens), self.key))
         with _span("sched.readback"):
             tok0 = np.asarray(tok0)
             fin = np.asarray(fin)
+            load = None if load is None else np.asarray(load)
+        self._mark_load(load)
         with _span("sched.commit"):
             for i, (b, s, e) in enumerate(chunks):
                 self.lengths[b] = e
@@ -2131,13 +2180,15 @@ class Scheduler:
                     jnp.asarray(rids), jnp.asarray(gens), self.key,
                     jnp.asarray(poison))
             if self.paged:
-                self.cache, tok, fin = fn(*args,
-                                          jnp.asarray(self.page_table))
+                out = fn(*args, jnp.asarray(self.page_table))
             else:
-                self.cache, tok, fin = fn(*args)
+                out = fn(*args)
+            (self.cache, tok, fin), load = self._split_load(out)
         with _span("sched.readback"):
             tok = np.asarray(tok)
             fin = np.asarray(fin)
+            load = None if load is None else np.asarray(load)
+        self._mark_load(load)
         with _span("sched.commit"):
             for b, s, e in chunks:
                 self.lengths[b] = e
@@ -2249,14 +2300,16 @@ class Scheduler:
                     jnp.asarray(rids), jnp.asarray(gens), self.key,
                     jnp.asarray(poison))
             if self.paged:
-                self.cache, out, n_emit, fin = fn(*args,
-                                                  jnp.asarray(self.page_table))
+                res = fn(*args, jnp.asarray(self.page_table))
             else:
-                self.cache, out, n_emit, fin = fn(*args)
+                res = fn(*args)
+            (self.cache, out, n_emit, fin), load = self._split_load(res)
         with _span("sched.readback"):
             out = np.asarray(out)
             n_emit = np.asarray(n_emit)
             fin = np.asarray(fin)
+            load = None if load is None else np.asarray(load)
+        self._mark_load(load)
         with _span("sched.commit"):
             for b, s, e in chunks:
                 self.lengths[b] = e
