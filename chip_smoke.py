@@ -43,8 +43,8 @@ SEED = 0
 # Phase 2 tolerances, relative L2 error of the kernel output on a row's
 # valid queries:
 # - against `ref.pim_attention_ref`, the two-pass oracle with the same LUT
-#   arithmetic: only the online (prefill) or split-K (decode) rescale
-#   rounding separates them.  On a v5e the 12 variants read 7.2e-05 to
+#   arithmetic: only the rounding of the online rescales (one max per KV
+#   block) separates them.  On a v5e the 12 variants read 7.2e-05 to
 #   1.3e-04; with the e.v dot at the default (one bf16 pass) precision
 #   1.9e-03 to 2.3e-03, with the one-hot exp LUT read there 9.4e-04 to
 #   1.8e-03.  The bound sits between, so dropping either HIGHEST pin fails;

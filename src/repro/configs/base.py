@@ -137,10 +137,10 @@ class ModelConfig:
     num_dense_layers: int = 0      # leading non-MoE layers (deepseek-moe: 1)
     moe: MoEConfig = MoEConfig()
     attn_impl: str = "behavioral"  # behavioral|kernel (serve-path attention)
-    # decode specialization of the kernel path: auto-select the split-K
+    # decode specialization of the kernel path: auto-select the
     # flash-decode kernel when a serve step has Sq == 1
     decode_kernel: bool = True
-    decode_block_k: int = 256      # KV partition size of the split-K grid
+    decode_block_k: int = 256      # KV block size of the decode grid
     # KV-cache storage precision: 8 = int8 values (default, the paper's
     # layout), 4 = blockwise dynamic-map codes packed two per byte (halves
     # KV bytes/token; scale planes are the same absmax/127 grid either way).

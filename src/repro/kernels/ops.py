@@ -152,12 +152,13 @@ def pim_flash_attention(
 ) -> jax.Array:
     """Fused flash-style PIM attention over the int8 KV cache.
 
-    Single-token steps (Sq == 1) auto-dispatch to the split-K flash-decode
-    kernel when `decode_kernel` is set — full grid occupancy across KV
-    partitions instead of one padded q block serializing over the cache.
-    `force_decode_kernel` extends that dispatch to Sq > 1: speculative
-    VERIFY launches score each row's q_len drafted positions through the
-    split-K grid, keeping every position bit-identical to the Sq == 1
+    Single-token steps (Sq == 1) auto-dispatch to the flash-decode kernel
+    when `decode_kernel` is set — the q heads of a KV group packed into
+    one tile, reading each KV head's cache once, instead of one padded q
+    block per q head.  `force_decode_kernel` extends that dispatch to
+    Sq > 1: speculative VERIFY launches score each row's q_len drafted
+    positions through the decode grid, keeping every position
+    bit-identical to the Sq == 1
     decode step it replaces (the auto-rule would pick the prefill kernel,
     whose numerics only match to rounding).
 
@@ -202,17 +203,20 @@ def pim_paged_flash_attention(
     force_decode_kernel: bool = False,
 ) -> jax.Array:
     """Fused PIM attention over the paged KV pool: both kernels walk the
-    slot's page-table row instead of a contiguous cache.  Every KV
-    partition of the decode kernel IS one page; a grid step of it fetches
-    a block of the row's pages by async copies (see `pim_decode`).  The
-    prefill kernel's KV axis runs over table entries, one page a step.
+    slot's page-table row instead of a contiguous cache.  A grid step of
+    the decode kernel fetches a block of `pim_decode.paged_block_pages`
+    consecutive pages of the row by async copies and folds it into the
+    row's running sums as one KV block (see `pim_decode`); the prefill
+    kernel's KV axis runs over table entries, one page a step.
     Bit-identical to `pim_flash_attention` over a dense cache holding the
-    same tokens with block_k == page_size.
+    same tokens with the KV block of a grid step: `decode_block_k` == that
+    block's tokens for the decode kernel, block_k == page_size for the
+    prefill kernel.
 
     `q_len` is the optional (B,) ragged-Q vector (valid query rows per slot;
     0 = the row contributes nothing to this launch and costs zero compute).
     `force_decode_kernel` routes Sq > 1 speculative-verify launches through
-    the split-K decode grid (see `pim_flash_attention`).
+    the decode grid (see `pim_flash_attention`).
 
     Sliding-window layers are not paged (the scheduler gates them out), so
     there is no `window` parameter here.

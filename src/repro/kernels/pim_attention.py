@@ -133,6 +133,49 @@ def _kv4_dequant(src_ref, dst_ref, levels_f: jax.Array) -> jax.Array:
     return dst_ref[...]
 
 
+def _online_reset(m_ref, den_ref, acc_ref):
+    """The empty running state of `_online_fold`: max `_NEG`, sums 0."""
+    m_ref[...] = jnp.full_like(m_ref, _NEG)
+    den_ref[...] = jnp.zeros_like(den_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def _online_fold(m_ref, den_ref, acc_ref, codes, exp, lut, values,
+                 frac_bits: int):
+    """Fold a KV block into the running (max, denominator, acc) of its rows.
+
+    `codes` are the block's (r, c) score codes, `_NEG` where masked;
+    `exp(codes, m)` is their masked LUT exp against the (r, 1) max m, whose
+    columns are the rows of the dequantized V that `values()` builds (it
+    may drop padding lanes; built after the exp, so that V is not live
+    across the exp's row loop).  The running sums move to the new max by a
+    factor from the SAME LUT, exp(-d*s) = table[d] / 2^frac, read exactly
+    by `lut` ((r, 1) int32 indices -> table values): 0 from the empty
+    state, exactly 1.0 where the max does not move.  So a fully masked
+    block (e = 0 against finite V) leaves the sums bit-unchanged, as if it
+    were skipped."""
+    m_old = m_ref[...]
+    m_new = jnp.maximum(m_old, jnp.max(codes, axis=-1, keepdims=True))
+    d = jnp.clip(m_new - m_old, 0, 255).astype(jnp.int32)
+    resc = lut(d) / float(1 << frac_bits)
+    resc = jnp.where(m_old <= _NEG / 2, jnp.zeros_like(resc), resc)
+    e = exp(codes, m_new)
+    den_ref[...] = den_ref[...] * resc + jnp.sum(e, axis=-1, keepdims=True)
+    pv = jax.lax.dot_general(
+        e, values(), (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    acc_ref[...] = acc_ref[...] * resc + pv
+    m_ref[...] = m_new
+
+
+def _online_result(den_ref, acc_ref):
+    """The rows' attention output from the running sums: acc / denominator
+    (exact zeros for rows that folded nothing in)."""
+    return acc_ref[...] / jnp.maximum(den_ref[...], 1.0)
+
+
 def _block_needed(k_start, block_k, q_lo, q_hi, kv_len, causal: bool,
                   window: int):
     """Can KV block [k_start, k_start+block_k) contribute to queries at
@@ -160,9 +203,7 @@ def _attn_kernel(
 
     @pl.when(ki == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG)
-        denom_ref[...] = jnp.zeros_like(denom_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _online_reset(m_ref, denom_ref, acc_ref)
         iters_ref[...] = jnp.zeros_like(iters_ref)
 
     # each grid row reads ITS sequence's [q_offset, kv_len, q_len] — ragged
@@ -231,36 +272,24 @@ def _attn_kernel(
             mask &= k_pos > q_pos - window
         codes = jnp.where(mask, codes, _NEG)
 
-        # online LUT softmax update
-        m_old = m_ref[...]                 # (bq, 1)
-        m_new = jnp.maximum(m_old, jnp.max(codes, axis=-1, keepdims=True))
-        table_f = table_ref[...].astype(jnp.float32)
-        # rescale factor for the running sums comes from the SAME LUT
-        d_resc = jnp.clip(m_new - m_old, 0, 255).astype(jnp.int32)
-        resc = _lut_gather(d_resc, table_f) / float(1 << table_frac_bits)
-        resc = jnp.where(m_old <= _NEG / 2, jnp.zeros_like(resc), resc)
-
-        e = _lut_exp(codes, m_new, lut_ref, table_f)
-
-        denom_ref[...] = denom_ref[...] * resc + jnp.sum(e, axis=-1, keepdims=True)
-        vs = vs_ref[...].reshape(block_k, 1)        # (bk, 1) f32
-        if kv_bits == 4:
-            v_deq = _kv4_dequant(v_ref, deq_ref[0],
-                                 lv_ref[...].astype(jnp.float32)) * vs
-        else:
+        def values():
+            vs = vs_ref[...].reshape(block_k, 1)    # (bk, 1) f32
+            if kv_bits == 4:
+                return _kv4_dequant(v_ref, deq_ref[0],
+                                    lv_ref[...].astype(jnp.float32)) * vs
             v = v_ref[...].reshape(block_k, v_ref.shape[-1])  # (bk, Dh) int8
-            v_deq = v.astype(jnp.float32) * vs
-        pv = jax.lax.dot_general(
-            e, v_deq, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST,
-        )
-        acc_ref[...] = acc_ref[...] * resc + pv
-        m_ref[...] = m_new
+            return v.astype(jnp.float32) * vs
+
+        # online LUT softmax update
+        table_f = table_ref[...].astype(jnp.float32)
+        _online_fold(m_ref, denom_ref, acc_ref, codes,
+                     lambda c, m: _lut_exp(c, m, lut_ref, table_f),
+                     lambda d: _lut_gather(d, table_f), values,
+                     table_frac_bits)
 
     @pl.when(ki == n_k_blocks - 1)
     def _flush():
-        out_ref[...] = (acc_ref[...] / jnp.maximum(denom_ref[...], 1.0))[None]
+        out_ref[...] = _online_result(denom_ref, acc_ref)[None]
 
 
 @functools.partial(

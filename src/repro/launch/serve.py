@@ -70,10 +70,10 @@ def main(argv=None):
     ap.add_argument("--attn-impl", default="",
                     choices=["", "behavioral", "kernel"])
     ap.add_argument("--no-decode-kernel", action="store_true",
-                    help="disable the split-K flash-decode kernel on the "
+                    help="disable the flash-decode kernel on the "
                          "kernel path (force the prefill kernel for Sq==1)")
     ap.add_argument("--decode-block-k", type=int, default=0,
-                    help="KV partition size of the split-K decode grid")
+                    help="KV block size of the decode grid")
     ap.add_argument("--kv-bits", type=int, default=0, choices=[0, 4, 8],
                     help="KV-cache storage precision: 8 = int8 values "
                          "(default), 4 = blockwise dynamic-map codes packed "

@@ -135,8 +135,8 @@ def _serve_attend(q, cache, offset, cfg: ModelConfig, window: int, causal: bool,
                   q_len=None, force_decode_kernel: bool = False):
     if cfg.attn_impl == "kernel":
         from repro.kernels import ops
-        # Sq == 1 steps dispatch to the split-K flash-decode kernel (full
-        # KV-partition grid occupancy) unless cfg.decode_kernel opts out.
+        # Sq == 1 steps dispatch to the flash-decode kernel (the GQA heads
+        # packed into one tile) unless cfg.decode_kernel opts out.
         # `force_decode_kernel` keeps that dispatch for Sq > 1 speculative
         # VERIFY rows (bit-identity with the per-token decode launches).
         return ops.pim_flash_attention(
@@ -186,7 +186,7 @@ def _mixed_attend(q, cache, offset, kv_len, seq_lens, decode_rows,
     The ragged-Q prefill launch serves the prefill-chunk rows (decode rows
     are masked to q_len 0 — zero KV iterations); the decode launch serves
     the decode rows through EXACTLY the dispatch an unchunked decode step
-    uses (split-K decode kernel, or the prefill kernel when
+    uses (flash-decode kernel, or the prefill kernel when
     cfg.decode_kernel is off) with prefill rows masked to kv_len 0 — also
     zero compute.  Each row therefore pays only its own KV blocks AND
     produces the same bits it would produce in a separate unchunked
@@ -253,7 +253,7 @@ def attn_block_fwd_serve(params, x, cache: A.KVCache, offset, cfg: ModelConfig,
     Speculative verify mode: `verify_len` (static int > 1) widens the
     decode class — a decode row's seq_lens may be up to `verify_len`
     (current token + drafted continuations), all verified through the
-    split-K decode launch in one step.  The behavioral path again needs
+    decode-kernel launch in one step.  The behavioral path again needs
     no routing (ragged per-row positions already cover it).
 
     Returns (x, cache); a MoE block returns (x, cache, load), the load

@@ -192,7 +192,7 @@ def make_generate_fn(model: Model, prompt_len: int, max_new_tokens: int,
     `tok0` is the (B, 1) token sampled from the prefill logits.  The whole
     token loop is one `lax.scan` with the cache donated: per-token work is a
     single already-compiled device step, which is what makes the decode
-    kernel's split-K grid the only per-token cost.
+    kernel's grid the only per-token cost.
 
     lru_cached on (model, shape, sampling) so repeated `generate` calls with
     the same Model instance reuse the traced/compiled program instead of
@@ -443,7 +443,7 @@ def make_mixed_step_fn(model: Model, n: int, pad_len: int,
     offs[b]), or nothing (seq_lens[b] == 0 — idle/stalled, zero compute).
 
     One forward advances every row's cache; attention routes decode rows
-    through the split-K decode launch and chunk rows through the ragged-Q
+    through the decode-kernel launch and chunk rows through the ragged-Q
     prefill launch inside the same program (`blocks._mixed_attend`), so
     each row is bit-identical to its unchunked dispatch.  A token is
     sampled for every row from its last valid position with per-(rid,
@@ -546,7 +546,7 @@ def make_spec_step_fn(model: Model, n: int, pad_len: int, verify_len: int,
     token plus up to `verify_len - 1` drafted tokens (seq_lens[b] = 1 +
     k_b), prefill-chunk rows their chunk, idle rows nothing.  One forward
     verifies every drafted position — attention routes decode rows
-    through the multi-row split-K decode launch (`force_decode_kernel`),
+    through the multi-row decode-kernel launch (`force_decode_kernel`),
     so each drafted position is scored bit-identically to the 1-token
     decode step it replaces — and the model returns logits at ALL
     `verify_len` columns (`logit_positions`).
@@ -815,7 +815,7 @@ class Scheduler:
     The step's dispatch shape is `mixed_dispatch`:
 
       * ``"fused"`` (default) — ONE (B, L) mixed rectangle: decode rows
-        contribute 1 token at column 0 and route through the very split-K
+        contribute 1 token at column 0 and route through the very decode-kernel
         launch an unchunked decode step uses, prefill rows through the
         ragged-Q prefill launch, inside the same program
         (`blocks._mixed_attend`; idle rows cost zero KV iterations via the
@@ -846,7 +846,7 @@ class Scheduler:
     `draft_mode="ngram"` — the seam where a small zoo draft model plugs in
     later) for up to `draft_len` draft tokens, and the decode row carries
     [current token, drafts...] as a q_len = 1 + k ragged verify row — ONE
-    model pass scores every drafted position (multi-row split-K decode
+    model pass scores every drafted position (multi-row decode-kernel
     launch, bit-identical per position to the 1-token steps it replaces).
     The longest accepted prefix plus a bonus/correction token is emitted:
     up to `draft_len + 1` tokens per step per slot.  Greedy streams are

@@ -12,7 +12,7 @@
     behavioral + kernel)
   * mixed-step Scheduler bit-parity vs the unchunked scheduler: greedy and
     sampled, dense and paged, prefix sharing on and off, kernel path with
-    the split-K decode kernel enabled, eviction under a starved pool
+    the flash-decode kernel enabled, eviction under a starved pool
   * structural no-stall property: decoding slots keep emitting while a long
     prompt is mid-prefill
 """
@@ -141,7 +141,7 @@ def test_ragged_q_prefill_kernel_masking_and_early_out():
 
 
 def test_ragged_q_decode_kernel_skip_rows():
-    """q_len == 0 rows of the split-K decode launch run zero partitions and
+    """q_len == 0 rows of the decode-kernel launch run zero KV blocks and
     return exact zeros; q_len == 1 rows are bit-identical to an unmasked
     launch — the mixed step's complementary early-out pair."""
     B, max_len, H, Hkv, Dh, bk = 4, 128, 4, 2, 32, 32
@@ -305,7 +305,7 @@ def test_mixed_parity_sampled(smoke_model):
 
 
 def test_mixed_parity_kernel_paths(kernel_model):
-    """Kernel path with the split-K decode kernel enabled: decode rows of a
+    """Kernel path with the flash-decode kernel enabled: decode rows of a
     mixed step route through the SAME kernel dispatch an unchunked decode
     step uses, so outputs stay bit-identical — dense and paged, fused and
     paired."""

@@ -1,11 +1,12 @@
-"""Split-K decode kernel + grid-pruning validation (interpret mode).
+"""Flash-decode kernel + grid-pruning validation (interpret mode).
 
-Covers the ISSUE perf acceptance criteria:
   * decode-vs-prefill-kernel and decode-vs-fp parity of `pim_decode_pallas`
-  * kv_len early-exit: decode touches only ceil(kv_len/block_k) partitions,
+  * kv_len early-exit: decode touches only ceil(kv_len/block_k) blocks,
     independent of the padded cache max_len
   * causal / window block pruning is bit-equivalent to the dense grid and
     executes the analytically expected number of block iterations
+  * the online accumulator: rescales against the two-pass oracle, fully
+    masked blocks as exact no-ops, stale pool pages as exact zeros
 """
 import jax
 import jax.numpy as jnp
@@ -17,7 +18,7 @@ from repro.core import attention as attn
 from repro.core.attention import expected_kv_block_iters
 from repro.kernels import ops, ref
 from repro.kernels.pim_attention import pim_attention_pallas
-from repro.kernels.pim_decode import pim_decode_pallas
+from repro.kernels.pim_decode import paged_block_pages, pim_decode_pallas
 
 
 def _setup(key, B, Sq, max_len, kv_len, H, Hkv, Dh, scale=0.5):
@@ -286,3 +287,148 @@ def test_decode_window_parity():
     exp = expected_kv_block_iters(1, max_len, kv_len - 1, 1, 32,
                                   causal=True, window=W, kv_valid_len=kv_len)
     np.testing.assert_array_equal(np.asarray(iters.sum(axis=1)), exp)
+
+
+# ---------------------------------------------------------------------------
+# the online accumulator: one running (max, denominator, acc) per grid row
+# ---------------------------------------------------------------------------
+def _paged(k, v, lens, ps, rng, extra_pages=0, kv_bits=8):
+    """K/V (B, S, Hkv, Dh) written to a page pool through a random page
+    table (-1 past each row's pages; page 0, the trash page, never
+    assigned): (pool, table)."""
+    B, S, Hkv, Dh = k.shape
+    n = S // ps
+    P = B * n + 1 + extra_pages
+    perm = rng.permutation(np.arange(1, P))
+    pt = np.full((B, n), -1, np.int32)
+    i = 0
+    for b in range(B):
+        for j in range(-(-int(lens[b]) // ps)):
+            pt[b, j] = perm[i]
+            i += 1
+    pool = attn.paged_cache_write(
+        attn.init_paged_kv_cache(P, ps, Hkv, Dh, kv_bits=kv_bits), k, v,
+        jnp.zeros(B, jnp.int32), PIMConfig(), jnp.asarray(pt),
+        seq_lens=jnp.asarray(lens, jnp.int32))
+    return pool, jnp.asarray(pt)
+
+
+@pytest.mark.parametrize("walk", ["dense", "paged"])
+def test_decode_rising_max_matches_ref(walk):
+    """A row of 24 KV blocks whose scores climb along the cache: each
+    block raises the running max, so every step rescales the running sums
+    by the LUT.  The result stays within the two-pass oracle's bound, on
+    the dense walk and on the paged block walk (blocks of 8 pages)."""
+    B, H, Hkv, Dh, ps, n_blocks = 1, 2, 1, 128, 16, 24
+    S = n_blocks * 128
+    key = jax.random.PRNGKey(24)
+    u = jax.random.normal(key, (Dh,))
+    q = jnp.broadcast_to(u, (B, 1, H, Dh))
+    ramp = 6.0 * jnp.arange(S) / S                 # score climbs 0 -> 6
+    k = (0.5 * jax.random.normal(jax.random.fold_in(key, 1), (B, S, Hkv, Dh))
+         + ramp[None, :, None, None] * (u / jnp.linalg.norm(u)))
+    v = jax.random.normal(jax.random.fold_in(key, 2), (B, S, Hkv, Dh)) * 0.5
+    cache = attn.cache_write(attn.init_kv_cache(B, S, Hkv, Dh), k, v, 0,
+                             PIMConfig())
+    qq = _layout(q, cache)
+    off = jnp.int32(S - 1)
+    # the score codes' block maxima climb: the max moves late, many times
+    s = (qq[0][0].astype(jnp.float32) @ qq[2][0].astype(jnp.float32).T
+         * qq[1][0][:, None] * qq[3][0][None] / Dh ** 0.5)
+    block_max = np.asarray(jnp.round(s * 16).reshape(n_blocks, -1).max(-1))
+    assert np.argmax(block_max) >= n_blocks - 3
+    assert block_max[-1] - block_max[0] > 40
+    assert (np.diff(block_max) > 0).sum() >= n_blocks // 2
+    if walk == "dense":
+        o = pim_decode_pallas(*qq, off, cache.length, block_k=128,
+                              interpret=True)
+    else:
+        pool, pt = _paged(k, v, [S], ps, np.random.RandomState(0))
+        kq, ks, vq, vs = ops.paged_kernel_layout(pool)
+        assert paged_block_pages(ps, Dh, Dh, pt.shape[1]) == 8
+        q_q, qs = ops._q_kernel_layout(q, PIMConfig().input_bits)
+        o = pim_decode_pallas(q_q, qs, kq, ks, vq, vs, off,
+                              jnp.asarray([S], jnp.int32), interpret=True,
+                              page_table=pt)
+    o_r = ref.pim_attention_ref(*qq, off, S)
+    rel = jnp.linalg.norm(o - o_r) / (jnp.linalg.norm(o_r) + 1e-9)
+    assert float(rel) < 5e-3
+
+
+@pytest.mark.parametrize("walk", ["dense", "paged"])
+def test_verify_masked_block_is_exact_noop(walk):
+    """Verify rows at positions p, p+1, p+2 where p ends a KV block: the
+    next block is fully masked for row 0 but needed for rows 1 and 2.
+    Folding it in leaves row 0's running sums bit-unchanged, so row 0
+    equals its single step (kv_len p+1, which never runs that block)."""
+    B, H, Hkv, Sq = 2, 4, 2, 3
+    Dh, bk = (32, 16) if walk == "dense" else (128, 128)
+    p = 2 * bk - 1                       # the last token of block 1
+    S = 4 * bk
+    key = jax.random.PRNGKey(31)
+    q, k, v, cache = _setup(key, B, Sq, S, p + Sq, H, Hkv, Dh)
+    lens = jnp.full((B,), p + Sq, jnp.int32)
+    if walk == "dense":
+        qq = _layout(q, cache)
+        kv = qq[2:]
+        multi = dict(block_k=bk)
+    else:
+        pool, pt = _paged(jnp.pad(k, ((0, 0), (0, S - p - Sq), (0, 0),
+                                      (0, 0))),
+                          jnp.pad(v, ((0, 0), (0, S - p - Sq), (0, 0),
+                                      (0, 0))),
+                          [p + Sq] * B, 16, np.random.RandomState(1))
+        kv = ops.paged_kernel_layout(pool)
+        multi = dict(page_table=pt)
+    q_q, qs = ops._q_kernel_layout(q, PIMConfig().input_bits)
+    o_multi, it = pim_decode_pallas(
+        q_q, qs, *kv, jnp.full((B,), p, jnp.int32), lens,
+        q_len=jnp.full((B,), Sq, jnp.int32), interpret=True,
+        return_iters=True, **multi)
+    o_one, it1 = pim_decode_pallas(
+        q_q[:, :1], qs[:, :1], *kv, jnp.full((B,), p, jnp.int32),
+        jnp.full((B,), p + 1, jnp.int32), interpret=True, return_iters=True,
+        **multi)
+    # the verify launch ran a block (or page) the single step did not
+    assert int(np.asarray(it).sum()) > int(np.asarray(it1).sum())
+    np.testing.assert_array_equal(np.asarray(o_multi[:, 0]),
+                                  np.asarray(o_one[:, 0]))
+
+
+@pytest.mark.parametrize("walk,Dh,kv_bits", [
+    ("block", 128, 8), ("one_page", 32, 8), ("kv4_one_page", 128, 4)])
+def test_stale_pool_pages_add_exact_zeros(walk, Dh, kv_bits):
+    """Pages and scale rows the table never points at (the trash page,
+    pages of no slot) hold garbage bytes and NaN scales.  They reach no
+    output: every output is finite and bit-equal to the launch over the
+    same pool with those pages zeroed."""
+    B, H, Hkv, ps, S = 3, 4, 2, 16, 256
+    lens = [200, 37, 0]
+    key = jax.random.PRNGKey(47)
+    k = jax.random.normal(jax.random.fold_in(key, 1), (B, S, Hkv, Dh)) * 0.5
+    v = jax.random.normal(jax.random.fold_in(key, 2), (B, S, Hkv, Dh)) * 0.5
+    pool, pt = _paged(k, v, lens, ps, np.random.RandomState(2),
+                      extra_pages=6, kv_bits=kv_bits)
+    used = set(np.asarray(pt)[np.asarray(pt) >= 0].tolist())
+    stale = np.array([i for i in range(pool.k_q.shape[0]) if i not in used])
+    assert 0 in stale and len(stale) > 6
+
+    def fill(byte, scale):
+        return pool._replace(
+            k_q=pool.k_q.at[stale].set(byte), v_q=pool.v_q.at[stale].set(byte),
+            k_scale=pool.k_scale.at[stale].set(scale),
+            v_scale=pool.v_scale.at[stale].set(scale))
+
+    kq = ops.paged_kernel_layout(pool)[0]
+    assert (paged_block_pages(ps, kq.shape[-1], Dh, pt.shape[1]) > 1) == (
+        walk == "block")
+    q = jax.random.normal(jax.random.fold_in(key, 3), (B, 1, H, Dh)) * 0.5
+    q_q, qs = ops._q_kernel_layout(q, PIMConfig().input_bits)
+    lens_a = jnp.asarray(lens, jnp.int32)
+    offs = jnp.maximum(lens_a - 1, 0)
+    outs = [pim_decode_pallas(q_q, qs, *ops.paged_kernel_layout(
+                fill(byte, scale)), offs, lens_a, interpret=True,
+                page_table=pt)
+            for byte, scale in ((-77, jnp.nan), (0, 0.0))]
+    assert np.isfinite(np.asarray(outs[0])).all()
+    np.testing.assert_array_equal(np.asarray(outs[0]), np.asarray(outs[1]))

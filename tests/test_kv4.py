@@ -229,7 +229,8 @@ def test_kv4_paged_parity_random_tables_bitexact(seed):
     o_p = attn.pim_attention(q1, gath, PIM, LUT, offs1, out_dtype=jnp.float32)
     np.testing.assert_array_equal(np.asarray(o_d), np.asarray(o_p))
 
-    # decode kernel (pages ARE the split-K partitions)
+    # decode kernel (4-bit pools take the one-page walk: block_k ==
+    # page_size)
     qq = ops.kernel_attention_layout(q1, dense)
     ko_d = pim_decode_pallas(*qq, offs1, dense.length, block_k=ps,
                              interpret=True)

@@ -22,7 +22,7 @@ from repro.core import attention as attn
 from repro.data import pipeline as data
 from repro.kernels import ops
 from repro.kernels.pim_attention import pim_attention_pallas
-from repro.kernels.pim_decode import pim_decode_pallas
+from repro.kernels.pim_decode import paged_block_pages, pim_decode_pallas
 from repro.models.model_zoo import build_model
 from repro.runtime import serve_lib
 
@@ -43,6 +43,14 @@ def _random_table(rng, lens, ps, n_tables, extra_pages=0):
             pt[b, j] = perm[i]
             i += 1
     return pt, P
+
+
+def _step_tokens(kq, head_dim, pt):
+    """Tokens a grid step of the paged decode walk covers over the
+    head-major pool `kq` (Hkv, P, ps, stored width): the dense walk's
+    block_k at which the paged output is bit-identical."""
+    ps = kq.shape[2]
+    return ps * paged_block_pages(ps, kq.shape[-1], head_dim, pt.shape[1])
 
 
 def _paired_caches(key, B, max_len, lens, Hkv, Dh, ps, rng):
@@ -103,7 +111,8 @@ def test_paged_cache_write_routing_and_trash_isolation():
 def test_paged_parity_random_tables_bitexact(seed):
     """Decode + chunked-prefill attention over a randomly permuted page
     table is bit-identical to the dense slot cache, on the behavioral
-    gather reference and both Pallas kernels."""
+    gather reference and both Pallas kernels (the decode kernel's dense
+    walk at the paged walk's tokens per grid step)."""
     B, max_len, H, Hkv, Dh, ps = 3, 64, 4, 2, 32, 16
     lens = np.array([[50, 17, 0], [64, 1, 33], [16, 15, 17]][seed], np.int32)
     rng = np.random.RandomState(seed)
@@ -119,12 +128,12 @@ def test_paged_parity_random_tables_bitexact(seed):
     o_p = attn.pim_attention(q1, gath, PIM, LUT, offs1, out_dtype=jnp.float32)
     np.testing.assert_array_equal(np.asarray(o_d), np.asarray(o_p))
 
-    # decode kernel (pages ARE the split-K partitions)
-    qq = ops.kernel_attention_layout(q1, dense)
-    ko_d = pim_decode_pallas(*qq, offs1, dense.length, block_k=ps,
-                             interpret=True)
+    # decode kernel: the dense walk at the paged walk's tokens per grid step
     q_q, qs = ops._q_kernel_layout(q1, PIM.input_bits)
     kq, ks, vq, vs = ops.paged_kernel_layout(pool)
+    qq = ops.kernel_attention_layout(q1, dense)
+    ko_d = pim_decode_pallas(*qq, offs1, dense.length,
+                             block_k=_step_tokens(kq, Dh, pt), interpret=True)
     ko_p = pim_decode_pallas(q_q, qs, kq, ks, vq, vs, offs1, lens_a,
                              interpret=True, page_table=pt)
     np.testing.assert_array_equal(np.asarray(ko_d), np.asarray(ko_p))
@@ -171,8 +180,8 @@ def test_paged_decode_zero_compute_on_unallocated_pages():
 
 def test_paged_decode_page_boundary_steps():
     """Decode exactly at page boundaries: kv_len of ps-1, ps, ps+1, 2*ps —
-    bit-identical to dense, and the partition count steps up exactly when a
-    new page starts being read."""
+    bit-identical to dense at the paged walk's tokens per grid step, and
+    the page count steps up exactly when a new page starts being read."""
     ps, Hkv, H, Dh = 16, 2, 4, 32
     max_len = 4 * ps
     lens = np.array([ps - 1, ps, ps + 1, 2 * ps], np.int32)
@@ -183,11 +192,11 @@ def test_paged_decode_page_boundary_steps():
     q = jax.random.normal(key, (B, 1, H, Dh)) * 0.5
     lens_a = jnp.asarray(lens)
     offs = lens_a - 1
-    qq = ops.kernel_attention_layout(q, dense)
-    o_d = pim_decode_pallas(*qq, offs, dense.length, block_k=ps,
-                            interpret=True)
     q_q, qs = ops._q_kernel_layout(q, PIM.input_bits)
     kq, ks, vq, vs = ops.paged_kernel_layout(pool)
+    qq = ops.kernel_attention_layout(q, dense)
+    o_d = pim_decode_pallas(*qq, offs, dense.length,
+                            block_k=_step_tokens(kq, Dh, pt), interpret=True)
     o_p, iters = pim_decode_pallas(q_q, qs, kq, ks, vq, vs, offs, lens_a,
                                    interpret=True, return_iters=True,
                                    page_table=pt)
@@ -214,12 +223,12 @@ _BLOCK_WALK_CASES = {
 @pytest.mark.parametrize("case", sorted(_BLOCK_WALK_CASES))
 def test_paged_decode_block_walk_bitexact(case, monkeypatch):
     """The paged decode kernel's block walk (a grid step fetches a block
-    of pages by async copies) is bit-equal to the dense kernel at
-    block_k == page_size, with the same map of partitions run: ragged last
-    blocks, kv_len at and inside block edges, empty rows, q_len 0 rows,
-    scattered and shared page ids, verify rows.  Pools whose stored width
-    is not whole lanes (4-bit pages of Dh/2 = 64 bytes) keep the one-page
-    walk; 4-bit pages of 128 bytes take blocks too."""
+    of pages by async copies) is bit-equal to the dense kernel at block_k
+    == the block's tokens, with the same blocks run: ragged last blocks,
+    kv_len at and inside block edges, empty rows, q_len 0 rows, scattered
+    and shared page ids, verify rows.  4-bit pools keep the one-page walk,
+    bit-equal to the dense kernel at block_k == page_size with the same
+    map of pages run."""
     from repro.kernels import pim_decode as pd
     lens, max_len, q_len, sq, Dh, kv_bits, share = _BLOCK_WALK_CASES[case]
     B, H, Hkv, ps = len(lens), 4, 2, 16
@@ -260,20 +269,28 @@ def test_paged_decode_block_walk_bitexact(case, monkeypatch):
     offs = jnp.maximum(lens_a - jnp.maximum(ql, 1), 0)
     q = jax.random.normal(jax.random.fold_in(key, 3), (B, sq, H, Dh)) * 0.5
 
+    layout = ops.paged_kernel_layout(pool)
+    ppb = _step_tokens(layout[0], Dh, pt) // ps
     o_d, it_d = pim_decode_pallas(
         *ops.kernel_attention_layout(q, dense), offs, dense.length,
-        block_k=ps, interpret=True, return_iters=True, q_len=ql)
+        block_k=ppb * ps, interpret=True, return_iters=True, q_len=ql)
     assert blocks == []                      # the dense walk is untouched
     q_q, qs = ops._q_kernel_layout(q, PIM.input_bits)
     o_p, it_p = pim_decode_pallas(
-        q_q, qs, *ops.paged_kernel_layout(pool), offs, lens_a,
+        q_q, qs, *layout, offs, lens_a,
         interpret=True, return_iters=True, page_table=jnp.asarray(pt),
         q_len=ql)
     pim_decode_pallas.clear_cache()
     assert blocks == ([2] if kv_bits == 8 else [])
+    assert ppb == (2 if kv_bits == 8 else 1)
     np.testing.assert_array_equal(np.asarray(o_d), np.asarray(o_p))
-    np.testing.assert_array_equal(np.asarray(it_d), np.asarray(it_p))
-    assert np.asarray(it_p).sum() > 0 or not any(lens)
+    # the paged map counts pages read: a dense block ran iff any of its
+    # pages was read
+    it_p = np.asarray(it_p)
+    per_block = np.pad(it_p, ((0, 0), (0, (-it_p.shape[1]) % ppb))).reshape(
+        it_p.shape[0], -1, ppb).sum(axis=-1)
+    np.testing.assert_array_equal(np.asarray(it_d), np.minimum(per_block, 1))
+    assert it_p.sum() > 0 or not any(lens)
 
 
 # ---------------------------------------------------------------------------

@@ -102,3 +102,22 @@ def test_kernel_compiles_for_v5e(one_chip, kind, sq, kv_bits, paged):
         if dims.split(",")[-1] == "1"
         and order.split(",")[0] == str(dims.count(","))]
     assert not padded, padded
+
+
+@pytest.mark.parametrize("kind,sq,kv_bits", [
+    ("decode", 1, 8), ("verify", 5, 8), ("decode", 1, 4), ("verify", 5, 4)])
+def test_paged_decode_serving_call_writes_no_partials(one_chip, kind, sq,
+                                                      kv_bits):
+    """The serving call (no iteration map) writes only the attention
+    output: its output bytes are B*H*Sq*Dh f32, and neither they nor the
+    call's temporaries grow with the page table's width."""
+    sizes = []
+    for pages in (SK // PS, 2 * SK // PS):
+        args, kwargs = _operands(one_chip, sq, kv_bits, paged=True)
+        kwargs["page_table"] = jax.ShapeDtypeStruct(
+            (B, pages), jnp.int32, sharding=one_chip)
+        mem = pim_decode_pallas.lower(
+            *args, interpret=False, **kwargs).compile().memory_analysis()
+        assert mem.output_size_in_bytes == B * H * sq * DH * 4
+        sizes.append(mem.temp_size_in_bytes)
+    assert sizes[0] == sizes[1], sizes
